@@ -45,6 +45,8 @@ def main() -> None:
                     help="write a Chrome trace_event JSON of the run")
     args = ap.parse_args()
     chosen = args.only.split(",") if args.only else SUITES
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     trace = None
     if args.trace_out:

@@ -1,8 +1,3 @@
-import os
-if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=512")
-
 """Perf-iteration harness: compile a (arch × cell × profile) variant at small
 unrolled depth, difference against depth-1, and report the corrected
 three-term roofline — one hypothesis→measure cycle per invocation.
@@ -11,11 +6,13 @@ three-term roofline — one hypothesis→measure cycle per invocation.
       --cell train_4k --profile act_replicated
 
 Results append to runs/perf/log.json so EXPERIMENTS.md §Perf can cite the
-whole iteration history.
+whole iteration history.  The CLI compiles for 512 forced host devices and
+sets ``XLA_FLAGS`` for that under ``__main__``.
 """
 
 import argparse
 import json
+import os
 import time
 from pathlib import Path
 
@@ -100,4 +97,8 @@ def main():
 
 
 if __name__ == "__main__":
+    if "--xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                                   + " --xla_force_host_platform_device_count=512")
     main()

@@ -1,8 +1,3 @@
-import os
-if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=512")
-
 """Roofline analysis from the compiled dry-run artifacts.
 
 Three terms per (arch × cell), single-pod mesh (256 chips), TPU v5e
@@ -26,10 +21,14 @@ MODEL_FLOPS comes from analysis/model_flops.py (6·N_active·D etc.);
 ratio = MODEL_FLOPS / (HLO_FLOPs_per_chip × chips) — remat and redundant
 compute push it below the family's natural ceiling (≈0.33 for 6ND training
 accounting with full remat ≈ 0.25).
+
+The CLI compiles for 512 forced host devices; it sets ``XLA_FLAGS`` for
+that itself, under ``__main__``, so importing this module changes nothing.
 """
 
 import argparse
 import json
+import os
 from pathlib import Path
 
 import jax
@@ -37,8 +36,14 @@ import jax
 from repro.analysis.model_flops import model_flops
 from repro.configs import all_archs, get_arch
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s per chip
+# Published per-chip peaks keyed by ``jax.Device.device_kind``:
+# (peak FLOP/s, HBM bytes/s).  TPU v5e: 197 TFLOP/s bf16 and 819 GB/s HBM
+# (Google Cloud documentation, "TPU v5e").  A device missing here has no
+# roofline: nothing assumes a peak for it.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+}
+PEAK_FLOPS, HBM_BW = DEVICE_PEAKS["TPU v5 lite"]
 LINK_BW = 50e9  # B/s per ICI link
 CHIPS_SINGLE = 256
 COLL_FACTORS = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,
@@ -62,15 +67,6 @@ COLL_FACTORS = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,
 # ``nq`` = binding-table width, ``bitmap_words`` = label-bitmap words per
 # vertex, ``n_iters`` = binary-search iterations (≈ log2(max degree)).
 # --------------------------------------------------------------------------
-
-# (peak_flops/s, mem_bw B/s) used to turn a cost into model time; the TPU
-# row matches the chip constants above, cpu/gpu are order-of-magnitude
-# single-device defaults for annotation purposes.
-BACKEND_PEAKS = {
-    "tpu": (PEAK_FLOPS, HBM_BW),
-    "gpu": (6.0e13, 1.0e12),
-    "cpu": (2.0e11, 4.0e10),
-}
 
 KERNEL_MODELS = ("expand_filter", "ragged_expand", "delta_merge",
                  "delta_merge_labeled", "edge_exists")
@@ -115,12 +111,15 @@ def kernel_cost(kernel: str, *, expanded: float, rows: float = 0.0,
     return {"flops": flops, "bytes": bytes_, "coll": {}}
 
 
-def estimate_step_ms(kernel: str, backend: str = "cpu", **kw) -> dict:
-    """Roofline time estimate for one executor step on one device.
-    Returns ``{model_ms, dominant, flops, bytes}`` — what the executor
-    attaches to kernel-level trace spans."""
+def estimate_step_ms(kernel: str, device_kind: str, **kw) -> dict | None:
+    """Roofline time estimate for one executor step on one device of
+    kind ``device_kind``.  Returns ``{model_ms, dominant, flops, bytes}`` —
+    what the executor attaches to kernel-level trace spans — or ``None``
+    when :data:`DEVICE_PEAKS` has no published peaks for the device."""
+    if device_kind not in DEVICE_PEAKS:
+        return None
     cost = kernel_cost(kernel, **kw)
-    peak_f, bw = BACKEND_PEAKS.get(backend, BACKEND_PEAKS["cpu"])
+    peak_f, bw = DEVICE_PEAKS[device_kind]
     compute_s = cost["flops"] / peak_f
     memory_s = cost["bytes"] / bw
     return {"model_ms": max(compute_s, memory_s) * 1e3,
@@ -154,12 +153,9 @@ def _sub(a, b):
 
 
 def xla_cost(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized across jax versions: newer
-    jax returns a per-computation list of dicts, older a single dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``Compiled.cost_analysis()`` as one dict (empty when XLA reports
+    nothing)."""
+    return compiled.cost_analysis() or {}
 
 
 def _variant_cost(arch_name: str, cell: str, depth: tuple[int, int],
@@ -297,4 +293,8 @@ def main():
 
 
 if __name__ == "__main__":
+    if "--xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                                   + " --xla_force_host_platform_device_count=512")
     main()

@@ -110,7 +110,8 @@ def run_sharded(executor: Executor, plan: ExecPlan, mesh,
         _, _, _, count, ovf_step, _, _, _, _ = fn(
             chunk_row[0], count_row[0],
             jnp.zeros((width, max(1, plan.n_pvars)), jnp.int32),
-            jnp.zeros((width,), jnp.int32), jnp.zeros(0, jnp.int32), sarrs)
+            jnp.zeros((width,), jnp.int32), jnp.zeros(0, jnp.int32), sarrs,
+            executor.dg.arrays)
         total = jax.lax.psum(count, dp)
         ovf = (ovf_step < jnp.int32(n_steps)).astype(jnp.int32)
         any_ovf = jax.lax.pmax(ovf, dp)

@@ -20,11 +20,11 @@ CSR adjacency slices followed by vectorized filters:
 Capacity management (the adaptive pipeline): each step runs at its own
 power-of-two capacity from the planner's ``capacity_schedule`` (derived
 from per-step cardinality estimates), so early low-cardinality steps stop
-paying full-table compaction scatters.  A step whose ragged expansion
+paying full-table compactions.  A step whose ragged expansion
 exceeds its capacity *freezes* the chunk — the surviving table is carried
 through the remaining (inert) steps unchanged and the program reports the
 overflowing step index — and the host re-enters the plan from exactly that
-step with only that step's capacity doubled (*suffix-resume*), instead of
+step with only that step's capacity grown (*suffix-resume*), instead of
 redoing the whole chunk.  Learned capacities persist per plan, so later
 chunks start right-sized.  Results are exact — overflow never truncates.
 
@@ -33,8 +33,9 @@ only reads back a chunk's ``(count, overflow_step)`` scalars after the
 next chunk has been dispatched, hiding dispatch latency; with
 ``collect="count"`` the final step skips binding-table materialization and
 nothing but scalars crosses the device→host boundary.  Steps with no
-non-tree checks run through the fused Pallas expand/filter/compact kernel
-(:mod:`repro.kernels.expand_filter`) where the backend supports it.
+non-tree checks run through the fused expand/filter/compact kernel;
+:mod:`repro.kernels.ops` decides whether that is the Pallas kernel or its
+XLA implementation, and the step spans name which one ran.
 
 Non-tree join directions (uniform rule): for a check attached to query
 vertex u with candidate v_new and earlier vertex `other` bound to other_v,
@@ -59,6 +60,7 @@ import numpy as np
 from repro.core.planner import ExecPlan, Step
 from repro.core.planner.ir import _next_pow2
 from repro.kernels import ops as kops
+from repro.kernels import ref as kref
 from repro.rdf.graph import LabeledGraph
 from repro.resilience import faults as _faults
 from repro.resilience.cancel import CancelToken, QueryCancelled
@@ -315,13 +317,13 @@ def _pad_rows(x: jax.Array, rows: int) -> jax.Array:
     return jnp.pad(x, width, constant_values=-1)
 
 
-def _nontree_mask(dg: DeviceGraph, step: Step, sarr, b_rows, p_rows, v_new,
-                  opts: ExecOpts) -> jax.Array:
+def _nontree_mask(dg: DeviceGraph, arrays, step: Step, sarr, b_rows, p_rows,
+                  v_new, opts: ExecOpts) -> jax.Array:
     n = dg.pad_vertices if dg.delta_mode else dg.n_vertices
     ok = jnp.ones(v_new.shape[0], dtype=bool)
     for ci, c in enumerate(step.nontree):
         use_out = c.forward or c.self_loop
-        nbr = dg.arrays["out_nbr_el" if use_out else "in_nbr_el"]
+        nbr = arrays["out_nbr_el" if use_out else "in_nbr_el"]
         probe = v_new if c.self_loop else b_rows[:, c.other]
         psafe = jnp.clip(probe, 0, n - 1)
         if c.pvar_idx >= 0:
@@ -430,7 +432,7 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
     unchanged — and the returned ``ovf_step`` names that step (``len(steps)``
     = completed).  The frozen table is exactly the input the overflowing
     step needs on re-entry, so the host resumes from there with only that
-    step's capacity doubled.  ``caps`` must be monotone non-decreasing from
+    step's capacity grown.  ``caps`` must be monotone non-decreasing from
     ``n_in`` so the freeze carry is lossless.
 
     With ``collect="count"`` the final step only tallies survivors: no
@@ -443,11 +445,21 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
     ``pins``/``pouts`` the signature-prune probe's candidates in/out
     (``-1`` when the step has no probe).
 
+    ``arrays`` is ``dg.arrays``, the device-resident graph.  It is an input
+    of the program, never a closed-over value: jit embeds closed-over arrays
+    as constants, which would copy the whole graph into every compiled
+    program.
+
     ``params`` (int32 ``[plan.n_params]``, empty for fully baked plans) is a
     traced input: steps with ``param_slot >= 0`` check the new binding
     against ``params[slot]`` instead of the baked ``bound_id``, so one
     compiled program serves every constant instantiation of the shape — and
     ``jax.vmap`` over the params axis answers a whole batch per launch.
+
+    The returned program's ``kernel_log`` maps each of its traces
+    (:func:`_kernel_key` of its ``sarrs`` and ``arrays``) to the kernels
+    each step dispatched while it was traced, as ``{step: ["name:impl",
+    ...]}``: the kernels the compiled program runs.
     """
     nq = plan.query.n_vertices
     npv = max(1, plan.n_pvars)
@@ -463,7 +475,7 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
             raise ValueError("capacity schedule must be monotone "
                              f"non-decreasing (step {si}: {caps[si]} < {prev})")
 
-    def fn(chunk, chunk_count, p_init, org_init, params, sarrs):
+    def fn(chunk, chunk_count, p_init, org_init, params, sarrs, arrays):
         if not table_input:
             b = jnp.full((n_in, nq), _NULL, dtype=jnp.int32)
             b = b.at[:, plan.start_vertex].set(chunk)
@@ -481,6 +493,7 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
         pouts: list[jax.Array] = []
         cap_prev = n_in
         for si in range(start_step, stop):
+            kops.record_step(si)
             step = steps[si]
             sarr = sarrs[si]
             cap = caps[si]
@@ -512,14 +525,14 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
                 if t_iptr is not None:
                     t_lo, t_hi = t_iptr[vp], t_iptr[vp + 1]
                 deg = jnp.where(alive, deg, 0)
-                nbr_src = dg.arrays["out_nbr_el" if step.forward else "in_nbr_el"]
+                nbr_src = arrays["out_nbr_el" if step.forward else "in_nbr_el"]
                 deg_bound = int(
                     (dg.max_deg_out_el if step.forward
                      else dg.max_deg_in_el)[step.elabel]) \
                     if step.elabel < dg.base_elabels else 0
             else:  # predicate variable: plain CSR
                 iptr = sarr["all_iptr"] if dmode else \
-                    dg.arrays["out_indptr_all" if step.forward
+                    arrays["out_indptr_all" if step.forward
                               else "in_indptr_all"]
                 vp = jnp.clip(b[:, step.parent], 0, n - 1)
                 start = iptr[vp]
@@ -531,12 +544,12 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
                 if t_iptr is not None:
                     t_lo, t_hi = t_iptr[vp], t_iptr[vp + 1]
                 deg = jnp.where(alive, deg, 0)
-                nbr_src = dg.arrays["out_nbr_all" if step.forward
+                nbr_src = arrays["out_nbr_all" if step.forward
                                     else "in_nbr_all"]
                 deg_bound = 1 << dg.max_log_deg
 
             merged = d_iptr is not None or t_iptr is not None
-            coffs = jnp.cumsum(deg.astype(jnp.int32))
+            coffs = kref.prefix_sum(deg.astype(jnp.int32))
             total = coffs[-1]
             offs = (coffs - deg).astype(jnp.int32)
             ovf_here = total > cap
@@ -554,12 +567,12 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
             count_only = collect == "count" and si == n_steps - 1
 
             bitmap_src = (sarr.get("bitmap") if dmode
-                          else dg.arrays["label_bitmap"])
+                          else arrays["label_bitmap"])
             p_in = p_out = None
             if _fused_eligible(step, opts) and not count_only and not merged:
                 fmask = sarr.get("fmask")
                 fb_src = (sarr.get("filter_bitmap") if dmode
-                          else dg.arrays.get("filter_bitmap")) \
+                          else arrays.get("filter_bitmap")) \
                     if fmask is not None else None
                 if fmask is not None and fb_src is not None:
                     # composed label + signature probe: one superset test
@@ -612,7 +625,7 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
                             sb, db, sd, tl, th, j, valid,
                             n_iters=dg.max_log_deg)
                     else:
-                        lab_src = dg.arrays["out_lab_all" if step.forward
+                        lab_src = arrays["out_lab_all" if step.forward
                                             else "in_lab_all"]
                         v_new, el_new, ok = kops.delta_merge_labeled(
                             nbr_src, lab_src, d_nbr,
@@ -633,7 +646,7 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
 
                 if step.pvar_idx >= 0:  # tree-edge M_e binding
                     if el_new is None:
-                        lab_src = dg.arrays["out_lab_all" if step.forward
+                        lab_src = arrays["out_lab_all" if step.forward
                                             else "in_lab_all"]
                         el_new = jnp.where(valid, lab_src[idx], _NULL)
                     prev = p_rows[:, step.pvar_idx]
@@ -649,7 +662,7 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
                     ok &= kops.bitmap_superset(bm, sarr["label_mask"])
                 sig_mask = sarr.get("sig_mask")
                 sig_src = (sarr.get("sig") if dmode
-                           else dg.arrays.get("sig")) \
+                           else arrays.get("sig")) \
                     if sig_mask is not None else None
                 if sig_src is not None:
                     p_in = jnp.sum(ok.astype(jnp.int32))
@@ -661,20 +674,20 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
                     # not maintained across deltas, so snapshot execution
                     # skips them (they are pure optimizations)
                     safe = jnp.clip(v_new, 0, n - 1)
-                    ok &= dg.arrays["out_degree"][safe] >= jnp.int32(
+                    ok &= arrays["out_degree"][safe] >= jnp.int32(
                         step.min_out_ntypes)
-                    ok &= dg.arrays["in_degree"][safe] >= jnp.int32(
+                    ok &= arrays["in_degree"][safe] >= jnp.int32(
                         step.min_in_ntypes)
-                if "nlf_out_mask" in sarr and "nlf_out" in dg.arrays \
+                if "nlf_out_mask" in sarr and "nlf_out" in arrays \
                         and not dmode:
                     safe = jnp.clip(v_new, 0, n - 1)
-                    ok &= kops.bitmap_superset(dg.arrays["nlf_out"][safe],
+                    ok &= kops.bitmap_superset(arrays["nlf_out"][safe],
                                                sarr["nlf_out_mask"])
-                    ok &= kops.bitmap_superset(dg.arrays["nlf_in"][safe],
+                    ok &= kops.bitmap_superset(arrays["nlf_in"][safe],
                                                sarr["nlf_in_mask"])
                 if step.num_filters:
                     num_src = sarr.get("numeric") if dmode else (
-                        dg.arrays["numeric_value"] if has_numeric else None)
+                        arrays["numeric_value"] if has_numeric else None)
                     if num_src is not None:
                         vals = num_src[jnp.clip(v_new, 0, n - 1)]
                         for op, cval in step.num_filters:
@@ -685,8 +698,8 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
                             break
                         ok &= b_rows[:, w] != v_new
                 if step.nontree:
-                    ok &= _nontree_mask(dg, step, sarr, b_rows, p_rows, v_new,
-                                        opts)
+                    ok &= _nontree_mask(dg, arrays, step, sarr, b_rows,
+                                        p_rows, v_new, opts)
 
                 kept = jnp.sum(ok.astype(jnp.int32))
                 if count_only:
@@ -697,16 +710,14 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
                     org = _pad_rows(org, cap)
                     count = jnp.where(keep_new, kept, count)
                 else:
-                    pos = jnp.where(ok, jnp.cumsum(ok.astype(jnp.int32)) - 1,
-                                    cap)
-                    pos = jnp.where(keep_new, pos, cap)  # frozen: drop all
-                    # scatter into the padded previous table: rows the
-                    # scatter misses keep stale values, but those sit beyond
-                    # ``count`` and every consumer masks on it — and when
-                    # frozen the untouched pad IS the carried table
-                    b = _pad_rows(b, cap + 1).at[pos].set(b_rows)[:cap]
-                    p = _pad_rows(p, cap + 1).at[pos].set(p_rows)[:cap]
-                    org = _pad_rows(org, cap + 1).at[pos].set(org_rows)[:cap]
+                    # gather the kept rows to the front; rows past ``kept``
+                    # hold leftovers, which every consumer masks off by
+                    # ``count``, and a frozen step carries its input
+                    src = kref.compact_index(ok)
+                    b = jnp.where(keep_new, b_rows[src], _pad_rows(b, cap))
+                    p = jnp.where(keep_new, p_rows[src], _pad_rows(p, cap))
+                    org = jnp.where(keep_new, org_rows[src],
+                                    _pad_rows(org, cap))
                     count = jnp.where(keep_new, kept, count)
 
             totals.append(jnp.where(active, total, jnp.int32(-1)))
@@ -726,7 +737,28 @@ def build_chunk_fn(dg: DeviceGraph, plan: ExecPlan, caps: tuple[int, ...],
                 jnp.stack(pins) if pins else z,
                 jnp.stack(pouts) if pouts else z)
 
-    return fn
+    kernel_log: dict = {}
+
+    def traced(*args):
+        with kops.record_kernels() as kernels:
+            out = fn(*args)
+        kernel_log[_kernel_key(args[5], args[6])] = kernels
+        return out
+
+    traced.kernel_log = kernel_log
+    return traced
+
+
+def _kernel_key(sarrs, arrays):
+    """Which of a chunk program's traces ran: the kernels a step picks
+    depend on which step and graph arrays exist, not on their shapes."""
+    return jax.tree.structure((sarrs, arrays))
+
+
+def _note_kernels(kernels: dict[int, list[str]], fn, sarrs, arrays) -> None:
+    """Fold the kernels a called chunk program was traced with for these
+    inputs into a run's per-step record."""
+    kernels.update(fn.kernel_log[_kernel_key(sarrs, arrays)])
 
 
 def _jnp_cmp(vals, op: str, c: float):
@@ -751,16 +783,21 @@ def _jnp_cmp(vals, op: str, c: float):
 # --------------------------------------------------------------------------
 
 
-def _grow_caps(caps: list[int], si: int, max_cap: int) -> list[int]:
-    """Double step ``si``'s capacity after an overflow (raising once it is
-    already at ``max_cap``) and restore monotonicity for later steps.
-    Mutates and returns ``caps`` — the single overflow-retry policy shared
-    by the async drain and the profiled per-step path."""
+def _grow_caps(caps: list[int], si: int, max_cap: int,
+               need: int = 0) -> list[int]:
+    """Grow step ``si``'s capacity after an overflow to the power of two
+    that holds ``need`` rows — the expansion the overflowing dispatch
+    counted — and at least double it (raising once it is already at
+    ``max_cap``); restore monotonicity for later steps.  Growing to the
+    observed need re-enters once per overflow instead of once per
+    doubling, and each new capacity is a new compiled program.  Mutates
+    and returns ``caps`` — the single overflow-retry policy shared by the
+    async drain and the profiled per-step path."""
     if caps[si] >= max_cap:
         raise RuntimeError(
             f"binding-table overflow at max capacity {max_cap};"
             " raise ExecOpts.max_cap")
-    caps[si] = min(max_cap, caps[si] * 2)
+    caps[si] = min(max_cap, max(caps[si] * 2, _next_pow2(need)))
     for j in range(si + 1, len(caps)):
         caps[j] = max(caps[j], caps[si])
     return caps
@@ -791,6 +828,17 @@ def _small_plan(plan: ExecPlan, opts: ExecOpts) -> bool:
             and peak <= 4 * _SMALL_PLAN_ROWS)
 
 
+def _donates(table_input: bool, start: int, out_cap: int, n_in: int) -> bool:
+    """Does the chunk program for this window donate its binding-table
+    inputs?  Only re-entries (a table input past step 0) whose output
+    table has the input's shape can reuse the buffers in place — in
+    practice the profiled per-step path across equal-capacity steps.  A
+    donated input is gone after the call, so an overflow retry re-enters
+    from the frozen table the program returned.  Initial whole-chunk
+    dispatches are excluded: a legacy retry re-feeds the same host args."""
+    return table_input and start > 0 and out_cap == n_in
+
+
 def _empty_stats(n_steps: int) -> dict[str, Any]:
     return {
         "step_rows": [0] * n_steps,
@@ -802,49 +850,49 @@ def _empty_stats(n_steps: int) -> dict[str, Any]:
         "caps": [],
         "chunks": 0,
         "resumes": 0,
+        "donated": 0,  # dispatches that reused their input table buffers
         "compiles": 0,
         "wall_ms": 0.0,
     }
 
 
-def _step_kernel_name(dg: DeviceGraph, step: Step, sarr: dict,
-                      opts: ExecOpts, count_only: bool) -> str:
-    """Which kernel a step actually runs through — mirrors the dispatch
-    logic in ``build_chunk_fn`` (fused fast path vs. legacy ragged expand
-    vs. live-store delta merge)."""
-    merged = dg.delta_mode and ("d_iptr" in sarr or "t_iptr" in sarr)
-    if merged:
-        return "delta_merge" if step.elabel >= 0 else "delta_merge_labeled"
-    if _fused_eligible(step, opts) and not count_only:
-        return "expand_filter"
-    return "ragged_expand"
+# the expansion kernel among a step's kernels, by precedence: a live-store
+# merge resolves the slots of the ragged expansion that precedes it
+_EXPANSION_KERNELS = ("delta_merge_labeled", "delta_merge", "expand_filter",
+                      "ragged_expand")
 
 
-def _annotate_step_spans(trace, plan: ExecPlan, dg: DeviceGraph, sarrs,
-                         opts: ExecOpts, stats: dict, collect: str,
+def _expansion_kernel(kernels: list[str]) -> str:
+    """The expansion kernel's name among a step's ``name:impl`` kernels."""
+    names = {k.split(":")[0] for k in kernels}
+    return next((k for k in _EXPANSION_KERNELS if k in names), "")
+
+
+def _annotate_step_spans(trace, plan: ExecPlan, dg: DeviceGraph,
+                         kernels: dict[int, list[str]], stats: dict,
                          n_src: int) -> None:
     """Attach one summary span per plan step: executed-counter meta
-    (rows/kept/retries/capacity), the kernel that ran, and a roofline
-    estimate next to the measured wall time (profiled runs only have real
-    per-step durations; sampled traces report zero-duration spans)."""
-    try:
-        from repro.analysis.roofline import estimate_step_ms
-    except Exception:  # pragma: no cover - annotation must never fail a run
-        estimate_step_ms = None
-    backend = jax.default_backend()
+    (rows/kept/retries/capacity), the kernels its programs were traced with
+    (``kernels``, as ``name:impl``), and a roofline estimate next to the
+    measured wall time where the device has published peaks (profiled runs
+    only have real per-step durations; sampled traces report zero-duration
+    spans)."""
+    from repro.analysis.roofline import estimate_step_ms
+
+    device_kind = jax.devices()[0].device_kind
     nq = plan.query.n_vertices
     bitmap_words = int(dg.arrays["label_bitmap"].shape[1])
     wall = stats.get("step_wall_ms")
     caps = stats.get("caps") or []
     rows_in = float(n_src)
     for si, step in enumerate(plan.steps):
-        count_only = collect == "count" and si == len(plan.steps) - 1
-        kernel = _step_kernel_name(dg, step, sarrs[si], opts, count_only)
+        kernel = stats["step_kernels"][si]
         expanded = stats["step_rows"][si]
         kept = stats["step_kept"][si]
         cap = int(caps[si]) if si < len(caps) else 0
         meta: dict[str, Any] = {
-            "step": si, "kernel": kernel, "rows": expanded, "kept": kept,
+            "step": si, "kernel": kernel, "kernels": kernels.get(si, []),
+            "rows": expanded, "kept": kept,
             "retries": stats["step_retries"][si], "capacity": cap,
         }
         if step.sig_mask is not None:
@@ -856,15 +904,15 @@ def _annotate_step_spans(trace, plan: ExecPlan, dg: DeviceGraph, sarrs,
                     stats["step_prune_out"][si] / p_in, 4)
         if step.nontree:
             meta["nontree_checks"] = len(step.nontree)
-        if estimate_step_ms is not None:
-            est = estimate_step_ms(
-                kernel, backend=backend, expanded=expanded, rows=rows_in,
-                capacity=cap, nq=nq, bitmap_words=bitmap_words,
-                n_iters=dg.max_log_deg)
+        est = estimate_step_ms(
+            kernel, device_kind, expanded=expanded, rows=rows_in,
+            capacity=cap, nq=nq, bitmap_words=bitmap_words,
+            n_iters=dg.max_log_deg)
+        if est is not None:
             model_ms = est["model_ms"]
             for _ in step.nontree:
                 model_ms += estimate_step_ms(
-                    "edge_exists", backend=backend, expanded=expanded,
+                    "edge_exists", device_kind, expanded=expanded,
                     n_iters=dg.max_log_deg)["model_ms"]
             meta["model_ms"] = round(model_ms, 6)
             meta["model_dominant"] = est["dominant"]
@@ -913,7 +961,7 @@ class Executor:
         self._state: tuple[Any, DeviceGraph] = (view, dg)
         self._compiled: dict[tuple, Any] = {}
         self._plan_arrays_cache: dict[int, list[dict[str, jax.Array]]] = {}
-        # learned per-plan capacity schedules (overflow doublings persist,
+        # learned per-plan capacity schedules (overflow growth persists,
         # so later chunks / queries start right-sized)
         self._caps_cache: dict[tuple, list[int]] = {}
         # learned pipelined-vs-legacy choice for small plans (see
@@ -979,15 +1027,10 @@ class Executor:
             raw = build_chunk_fn(dg, plan, caps, n_in, opts,
                                  table_input, collect, start, stop)
             out_cap = caps[stop - 1] if stop > start else n_in
-            donate = ()
-            if (table_input and start > 0 and out_cap == n_in
-                    and jax.default_backend() in ("tpu", "gpu")):
-                # steady-state resume dispatches reuse the binding-table
-                # buffers in place (donation is a no-op on CPU).  Initial
-                # whole-chunk dispatches are excluded: legacy retry re-feeds
-                # the same host args, which donation would invalidate.
-                donate = (0, 2, 3)
+            donate = (0, 2, 3) if _donates(table_input, start, out_cap,
+                                           n_in) else ()
             fn = jax.jit(raw, donate_argnums=donate)
+            fn.kernel_log = raw.kernel_log
             self._compiled[key] = fn
         # freshness is returned (not kept on self) so concurrent runs on a
         # shared executor each see their own compile events
@@ -1267,6 +1310,20 @@ class Executor:
                 res.stats["degraded_level"] = level
             return res
 
+    def _warm_run(self, plan: ExecPlan, opts: ExecOpts,
+                  kw: dict) -> tuple[float, Result]:
+        """``(seconds, result)`` of a run of ``plan`` under ``opts`` that
+        compiled nothing.  A run that grew a capacity leaves the next one a
+        new program to compile, so runs repeat (at most three) until one
+        is warm."""
+        for _ in range(3):
+            t0 = time.perf_counter()
+            res = self.run(plan, _opts_override=opts, **kw)
+            t = time.perf_counter() - t0
+            if not res.stats.get("compiles"):
+                break
+        return t, res
+
     def _run_impl(
         self,
         plan: ExecPlan,
@@ -1305,10 +1362,10 @@ class Executor:
             # overhead (per-step capacity schedule, fused-kernel setup,
             # async bookkeeping) can exceed the work saved.  Estimates
             # can't settle which side a plan lands on, so probe once per
-            # plan signature: run each configuration twice (first to warm
-            # its compile cache, second timed) and remember the winner.
-            # Both configurations return identical results, so the probe
-            # is invisible to callers beyond one-time latency.
+            # plan signature: time one warm run of each configuration and
+            # remember the winner.  Both configurations return identical
+            # results, so the probe is invisible to callers beyond
+            # one-time latency.
             sig = plan.signature()
             mode = self._small_mode.get(sig)
             if mode is None:
@@ -1318,13 +1375,15 @@ class Executor:
                 kw = dict(collect=collect, state=state, params=params,
                           cancel=cancel)
                 res = self.run(plan, _opts_override=opts, **kw)
-                t0 = time.perf_counter()
-                res = self.run(plan, _opts_override=opts, **kw)
-                t_pipe = time.perf_counter() - t0
+                peak = max(res.stats.get("step_kept") or (), default=0)
+                if max(peak, res.count) > 4 * _SMALL_PLAN_ROWS:
+                    # the estimates that shortlisted the plan were wrong:
+                    # it is not small, so the pipeline stays, untimed
+                    self._small_mode[sig] = False
+                    return res
+                t_pipe, res = self._warm_run(plan, opts, kw)
                 self.run(plan, _opts_override=legacy, **kw)
-                t0 = time.perf_counter()
-                res_l = self.run(plan, _opts_override=legacy, **kw)
-                t_leg = time.perf_counter() - t0
+                t_leg, res_l = self._warm_run(plan, legacy, kw)
                 # require a clear win before abandoning the pipeline: the
                 # probe is a single sample and ties should keep defaults
                 mode = t_leg < 0.9 * t_pipe
@@ -1395,6 +1454,7 @@ class Executor:
 
         if profile:
             stats["step_wall_ms"] = [0.0] * n_steps
+        kernels: dict[int, list[str]] = {}  # step -> "name:impl" it ran
         total = 0
         out_b: list[np.ndarray] = []
         out_p: list[np.ndarray] = []
@@ -1407,9 +1467,10 @@ class Executor:
             if not extension:
                 chunk = np.full(chunk_size, -1, dtype=np.int32)
                 chunk[:n_real] = start_cands[offset:hi]
+                # host arrays: an eager jnp.zeros compiles per chunk size
                 return (jnp.asarray(chunk), jnp.int32(n_real),
-                        jnp.zeros((chunk_size, npv), jnp.int32),
-                        jnp.zeros((chunk_size,), jnp.int32))
+                        jnp.asarray(np.zeros((chunk_size, npv), np.int32)),
+                        jnp.asarray(np.zeros(chunk_size, np.int32)))
             bpad = np.full((chunk_size, nq), -1, dtype=np.int32)
             bpad[:n_real] = b0[offset:hi]
             ppad = np.full((chunk_size, npv), -1, np.int32)
@@ -1432,6 +1493,7 @@ class Executor:
             else:
                 with trace.span("compile" if fresh else "dispatch", **meta):
                     out = fn(*args)
+            _note_kernels(kernels, fn, sarrs, dg.arrays)
             if poison:
                 # injected silent corruption: zero this chunk's count so
                 # end-to-end checks can detect a poisoned dispatch
@@ -1446,7 +1508,8 @@ class Executor:
                                      collect, 0, n_steps, dg, opts)
             ci = stats["chunks"]
             stats["chunks"] += 1
-            return {"out": call_fn(fn, fresh, (*args, params_dev, sarrs),
+            return {"out": call_fn(fn, fresh,
+                                   (*args, params_dev, sarrs, dg.arrays),
                                    chunk=ci),
                     "args": args, "caps": used, "offset": offset}
 
@@ -1492,11 +1555,13 @@ class Executor:
                 # deadline before re-entering the plan
                 check_cancel()
                 stats["step_retries"][ovf] += 1
+                need = int(np.asarray(totals)[ovf - start])
                 if opts.suffix_resume:
                     # re-enter from the overflowing step only: the frozen
                     # table returned by the chunk program is exactly that
                     # step's input
-                    new_caps = _grow_caps(list(used), ovf, opts.max_cap)
+                    new_caps = _grow_caps(list(used), ovf, opts.max_cap,
+                                          need)
                     n_in = used[ovf - 1] if ovf > 0 else chunk_size
                     fn, fresh = self._get_fn(plan, tuple(new_caps), n_in,
                                              True, collect, ovf, n_steps, dg,
@@ -1505,24 +1570,26 @@ class Executor:
                      pouts) = call_fn(
                         fn, fresh,
                         (b[:n_in], count, p[:n_in], org[:n_in], params_dev,
-                         sarrs),
+                         sarrs, dg.arrays),
                         resume_step=ovf)
                     start = ovf
                     acc_from = ovf
                     stats["resumes"] += 1
                 else:
-                    # legacy: double every capacity, redo the whole chunk
+                    # legacy: grow every capacity alike, redo the whole chunk
                     if used[ovf] >= opts.max_cap:
                         raise RuntimeError(
                             f"binding-table overflow at max capacity "
                             f"{opts.max_cap}; raise ExecOpts.max_cap")
-                    new_caps = [min(opts.max_cap, c * 2) for c in used]
+                    new_caps = [min(opts.max_cap, max(c * 2, _next_pow2(need)))
+                                for c in used]
                     fn, fresh = self._get_fn(plan, tuple(new_caps),
                                              chunk_size, extension, collect,
                                              0, n_steps, dg, opts)
                     (b, p, org, count, ovf_step, totals, kepts, pins,
                      pouts) = call_fn(
-                        fn, fresh, (*rec["args"], params_dev, sarrs),
+                        fn, fresh,
+                        (*rec["args"], params_dev, sarrs, dg.arrays),
                         retry=True)
                     start = 0
                 used = new_caps
@@ -1533,9 +1600,9 @@ class Executor:
             c = int(count)
             total += c
             if collect == "bindings" and c:
-                out_b.append(np.asarray(b[:c]))
-                out_p.append(np.asarray(p[:c]))
-                o = np.asarray(org[:c])
+                out_b.append(_host_rows(b, c))
+                out_p.append(_host_rows(p, c))
+                o = _host_rows(org, c)
                 if not extension:
                     o = o + rec["offset"]  # chunk-local start index -> global
                 out_o.append(o)
@@ -1550,7 +1617,8 @@ class Executor:
                 self._run_profiled_chunk(plan, sarrs, offset, hi, chunk_size,
                                          extension, collect, caps_key, stats,
                                          host_args, drain, dg, trace,
-                                         params_dev, opts, check_cancel)
+                                         params_dev, opts, check_cancel,
+                                         kernels)
             else:
                 pending.append(dispatch(offset, hi))
                 if len(pending) >= max_inflight:
@@ -1561,15 +1629,12 @@ class Executor:
 
         stats["caps"] = list(self._caps_cache[caps_key])
         stats["wall_ms"] = (time.perf_counter() - t_run0) * 1e3
-        # which kernel each step ran through — cheap host-side lookup,
-        # consumed by the workload profiler's kernel-mix accounting
-        stats["step_kernels"] = [
-            _step_kernel_name(dg, st, sarrs[si], opts,
-                              collect == "count" and si == n_steps - 1)
-            for si, st in enumerate(plan.steps)]
+        # which expansion kernel each step ran, consumed by the workload
+        # profiler's kernel-mix accounting
+        stats["step_kernels"] = [_expansion_kernel(kernels.get(si, []))
+                                 for si in range(n_steps)]
         if trace is not None and n_steps:
-            _annotate_step_spans(trace, plan, dg, sarrs, opts, stats,
-                                 collect, n_src)
+            _annotate_step_spans(trace, plan, dg, kernels, stats, n_src)
         bindings = (np.concatenate(out_b) if out_b else _empty(plan)) \
             if collect == "bindings" else None
         pb = (np.concatenate(out_p) if out_p else _empty_p(plan)) \
@@ -1692,7 +1757,8 @@ class Executor:
             lane_ax = 0 if per_lane_start else None
             fn = jax.jit(jax.vmap(raw,
                                   in_axes=(lane_ax, lane_ax, None, None, 0,
-                                           None)))
+                                           None, None)))
+            fn.kernel_log = raw.kernel_log
             self._compiled[key] = fn
         p0 = jnp.zeros((chunk_size, npv), jnp.int32)
         o0 = jnp.zeros((chunk_size,), jnp.int32)
@@ -1708,7 +1774,7 @@ class Executor:
         try:
             poison = _faults.fire("dispatch")
             (b, p, org, count, ovf_step, totals, kepts, pins,
-             pouts) = fn(chunk_in, count_in, p0, o0, pmat, sarrs)
+             pouts) = fn(chunk_in, count_in, p0, o0, pmat, sarrs, dg.arrays)
         except Exception as e:  # noqa: BLE001 - filtered just below
             if not is_transient_fault(e):
                 raise
@@ -1729,13 +1795,13 @@ class Executor:
         # same sentinel contract as the single-query chunk program)
         tot_h, kep_h = np.asarray(totals), np.asarray(kepts)
         pin_h, pout_h = np.asarray(pins), np.asarray(pouts)
-        kernels = [_step_kernel_name(dg, st, sarrs[si], opts,
-                                     collect == "count" and si == n_steps - 1)
-                   for si, st in enumerate(plan.steps)]
+        traced = fn.kernel_log[_kernel_key(sarrs, dg.arrays)]
+        kernels = [_expansion_kernel(traced.get(si, []))
+                   for si in range(n_steps)]
         for li, qi in enumerate(live):
             if int(ovf_h[li]) < n_steps:
                 # overflowing lane: redo it alone — run()'s suffix-resume
-                # doubling is deterministic, so the answer is identical to
+                # growth is deterministic, so the answer is identical to
                 # a lane that had fit
                 results[qi] = self.run(plan, collect=collect, state=state,
                                        params=params_mat[qi], cancel=cancel)
@@ -1770,11 +1836,14 @@ class Executor:
                             drain, dg: DeviceGraph | None = None,
                             trace=None, params_dev=None,
                             opts: ExecOpts | None = None,
-                            check_cancel=None) -> None:
+                            check_cancel=None,
+                            kernels: dict | None = None) -> None:
         """Step-at-a-time execution of one chunk with host syncs, filling
-        per-step wall times; overflow handling is inherently suffix-resume
-        (each window re-runs alone with a doubled capacity)."""
+        per-step wall times and, in ``kernels``, the kernels each step's
+        program ran; overflow handling is inherently suffix-resume (each
+        window re-runs alone with a grown capacity)."""
         opts = self.opts if opts is None else opts
+        dg = self.dg if dg is None else dg
         if params_dev is None:
             params_dev = jnp.zeros(0, jnp.int32)
         n_steps = len(plan.steps)
@@ -1794,6 +1863,8 @@ class Executor:
                                          collect, si, si + 1, dg, opts)
                 if fresh:
                     stats["compiles"] += 1
+                if _donates(extension or si > 0, si, used[si], n_in):
+                    stats["donated"] += 1
                 span_cm = (trace.span("compile" if fresh else "dispatch",
                                       chunk=ci, step=si)
                            if trace is not None else None)
@@ -1802,11 +1873,13 @@ class Executor:
                 poison = _faults.fire("dispatch")
                 t0 = time.perf_counter()
                 if si == 0:
-                    out = fn(*args, params_dev, sarrs)
+                    out = fn(*args, params_dev, sarrs, dg.arrays)
                 else:
                     b, p, org, count = state
                     out = fn(b[:n_in], count, p[:n_in], org[:n_in],
-                             params_dev, sarrs)
+                             params_dev, sarrs, dg.arrays)
+                if kernels is not None:
+                    _note_kernels(kernels, fn, sarrs, dg.arrays)
                 if poison:
                     stats["poisoned"] = stats.get("poisoned", 0) + 1
                     out = (*out[:3], out[3] * 0, *out[4:])
@@ -1828,7 +1901,12 @@ class Executor:
                     break
                 stats["step_retries"][si] += 1
                 stats["resumes"] += 1
-                _grow_caps(caps, si, opts.max_cap)
+                _grow_caps(caps, si, opts.max_cap, int(totals[0]))
+                if si > 0:
+                    # retry from the frozen table the program returned: it
+                    # is this step's input, which the dispatch may have
+                    # donated (step 0 re-feeds its never-donated host args)
+                    state = (b, p, org, count)
         # hand the finished table to the shared collection path (the -1
         # counter vectors mean "already accumulated above")
         b, p, org, count = state
@@ -1839,6 +1917,13 @@ class Executor:
                        jnp.full(n_steps, -1, jnp.int32)),
                "args": args, "caps": tuple(caps), "offset": offset}
         drain(rec)
+
+
+def _host_rows(x: jax.Array, n: int) -> np.ndarray:
+    """The first ``n`` rows of a device table, on the host.  The device
+    slice is rounded up to a power of two rows: an eager slice compiles
+    once per length, and a run has a different count per chunk."""
+    return np.asarray(x[:min(x.shape[0], _next_pow2(n))])[:n]
 
 
 def _empty(plan: ExecPlan) -> np.ndarray:

@@ -164,12 +164,10 @@ def prune_candidates(g, q, u: int, cands: np.ndarray,
     req = required_signature(idx.n_bits, q, u, optional_groups)
     if not req.any():
         return cands
-    from repro.kernels import ops as kops
-
-    rows = signature_rows(g)
-    keep = np.asarray(kops.signature_filter(
-        rows, cands.astype(np.int32), req))
-    return cands[keep]
+    # on the host, where the planner runs: an eager device probe would
+    # upload the whole table and compile once per candidate-set size
+    rows = signature_rows(g)[cands]
+    return cands[np.all((rows & req) == req, axis=-1)]
 
 
 def patch_index(old: SignatureIndex, new_g: LabeledGraph, *,

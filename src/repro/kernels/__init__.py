@@ -1,17 +1,17 @@
-"""Pallas TPU kernels for the engine's compute hot-spots.
+"""Kernels for the engine's compute hot-spots.
 
 The paper's profiling singles out ExploreCandidateRegion and SubgraphSearch
-(IsJoinable in particular) as the dominating costs; the corresponding
-vectorized primitives get kernels here:
+(IsJoinable in particular) as the dominating costs.  Every vectorized
+primitive has a pure-jnp implementation in ``ref.py`` (ragged expansion,
+binary-search edge probes, delta merges, signature and bitmap filters);
+two also have Pallas TPU kernels:
 
-- ``edge_exists``       — batched binary search over CSR slices (IsJoinable)
 - ``sorted_intersect``  — tiled compare-all membership (+INT, VPU-shaped)
 - ``bitmap_filter``     — packed-bitmap superset probes (label / NLF filters)
-- ``segment_gather``    — fused gather + segment-sum (EmbeddingBag / GNN
-                          aggregation; shared with the model zoo)
 
-Every kernel has a pure-jnp oracle in ``ref.py``; ``ops.py`` dispatches by
-backend (Pallas on TPU, interpret mode for CPU validation, jnp otherwise).
+``ops.py`` dispatches through one table (``ops.TPU_IMPL``) that names, per
+kernel, whether a TPU runs its Pallas kernel or its XLA (jnp)
+implementation.
 """
 
 from repro.kernels import ops, ref

@@ -1,12 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile EVERY (architecture × input shape)
 over the production meshes, record memory/cost analysis + collective bytes.
 
-THE TWO LINES ABOVE MUST STAY FIRST — jax locks the device count at first
-initialization, and smoke tests / benches must NOT inherit 512 devices
-(hence no global conftest/env setting).
+The CLI sets ``XLA_FLAGS`` to 512 forced host devices under ``__main__``,
+before anything initializes a jax backend (jax locks the device count at
+first initialization).  Importing this module sets nothing: tests and
+benches must not inherit 512 devices.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun                      # all cells
@@ -19,6 +17,7 @@ so interrupted sweeps resume).
 
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -152,8 +151,8 @@ def main() -> None:
     args = ap.parse_args()
 
     assert len(jax.devices()) == 512, (
-        "dryrun needs 512 forced host devices; do not import jax before "
-        "this module sets XLA_FLAGS")
+        "dryrun needs 512 forced host devices; run it as "
+        "`python -m repro.launch.dryrun`, which sets XLA_FLAGS")
 
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     archs = [args.arch] if args.arch else all_archs()
@@ -174,4 +173,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

@@ -31,6 +31,7 @@ from repro.serve.metrics import ServeMetrics
 from repro.serve.scheduler import Scheduler
 from repro.serve.server import DatasetRegistry, make_server
 from repro.utils import get_logger
+from repro.utils.compile_cache import enable_compile_cache
 
 log = get_logger("launch.serve")
 
@@ -278,6 +279,7 @@ def main(argv=None) -> None:
                      help="how long a plan stays at its degraded level "
                           "before re-probing one level lower (default 30s)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.log_json:
         from repro.utils import set_json_logging

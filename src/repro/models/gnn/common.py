@@ -3,8 +3,7 @@
 JAX has no native sparse message passing (BCOO only) — per the assignment,
 aggregation is built on ``jax.ops.segment_sum`` / ``segment_max`` over an
 edge-index scatter.  These wrappers add degree normalization, mean/std
-aggregators, and a numerically safe segment softmax; the engine's
-``kernels/segment_gather`` provides the fused Pallas path where applicable.
+aggregators, and a numerically safe segment softmax.
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """DLRM (arXiv:1906.00091) — RM-2 shape: 26 sparse + 13 dense features.
 
 JAX has no native EmbeddingBag: lookups are ``jnp.take`` + masked sum over a
-fixed-hotness index layout (the Pallas ``segment_gather`` kernel provides
-the fused path), which IS the system's hot loop at serving time.  Dot-product
+fixed-hotness index layout, which IS the system's hot loop at serving
+time.  Dot-product
 feature interaction (upper triangle) + bottom/top MLPs, BCE loss.
 
 ``retrieval_score`` implements the retrieval_cand shape: one user query

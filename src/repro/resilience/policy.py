@@ -31,15 +31,23 @@ from repro.resilience.faults import InjectedFault
 MAX_LEVEL = 3
 
 _TRANSIENT_MARKERS = ("RESOURCE_EXHAUSTED", "resource_exhausted", "out of memory", "OOM")
+# The TPU compiler refusing a kernel (a Mosaic lowering error, or a kernel
+# that does not fit VMEM, which XLA reports as RESOURCE_EXHAUSTED) is
+# deterministic: a retry compiles the same kernel again, and a degraded
+# config that avoids the kernel would hide that it cannot run.  It raises.
+_COMPILE_REFUSAL_MARKERS = ("Mosaic", "memory space vmem")
 
 
 def is_transient_fault(exc: BaseException) -> bool:
-    """True for faults worth retrying/degrading over (OOM-shaped)."""
+    """True for faults worth retrying/degrading over (OOM-shaped), and
+    never for a compile-time refusal by the TPU compiler."""
     if isinstance(exc, InjectedFault):
         return exc.kind in ("oom", "compile_error")
     if isinstance(exc, MemoryError):
         return True
     text = str(exc)
+    if any(m in text for m in _COMPILE_REFUSAL_MARKERS):
+        return False
     return any(m in text for m in _TRANSIENT_MARKERS)
 
 

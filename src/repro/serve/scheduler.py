@@ -348,6 +348,11 @@ class Scheduler:
         try:
             finished = flight.done.wait(max(0.0, deadline - time.monotonic()))
             ms = (time.perf_counter() - t0) * 1e3
+            if (isinstance(flight.error, QueryCancelled)
+                    and time.monotonic() >= deadline):
+                # the executor saw this waiter's deadline before the wait
+                # returned: answer as the deadline, like an unfinished wait
+                finished = False
             if not finished:
                 self.metrics.record(dataset, "timeout", ms)
                 qw, ex = flight.timing_ms()

@@ -7,7 +7,7 @@ base graph plus an in-memory delta overlay (COO insert buffers and
 tombstones over base edges), and hands out cheap immutable
 :class:`Snapshot` views that queries execute against while writers keep
 appending.  The executor merges base-CSR adjacency with the snapshot's
-small sorted delta adjacency per expansion step (``kernels/delta_merge``),
+small sorted delta adjacency per expansion step (``kernels.ops.delta_merge``),
 so no CSR rebuild happens on the write path; a threshold-triggered
 compaction folds the delta into a fresh ``LabeledGraph`` and *patches* the
 cached ``GraphStats`` incrementally instead of recomputing them.

@@ -15,7 +15,7 @@ everything the *planner* touches host-side (``candidates_with_labels``,
 ``out/inc.degree``) — all answers are exact for the merged graph.  The
 *executor* recognizes ``is_snapshot`` and merges base CSR adjacency with
 the snapshot's delta adjacency per step (see ``core.exec`` and
-``kernels/delta_merge``).
+``kernels.ops.delta_merge``).
 
 ``compact()`` folds the delta into a fresh ``LabeledGraph`` (vertex /
 edge-label ids are preserved, so compiled plans and the dictionary stay

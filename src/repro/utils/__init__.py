@@ -1,4 +1,3 @@
-import repro.utils.compat  # noqa: F401  (installs jax version shims)
 from repro.utils.logging import get_logger, log_event, set_json_logging
 from repro.utils.timing import Timer, timed
 

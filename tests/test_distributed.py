@@ -11,15 +11,8 @@ import sys
 import textwrap
 
 import numpy as np
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# Both xfails below are deterministic jax 0.4.x lowering artifacts (see each
-# marker's reason).  Conditioning on the exact version line + strict=True
-# means they must fail on 0.4.x and must pass the moment the image moves to
-# jax>=0.5 — a rotted marker shows up as XPASS-strict instead of hiding.
-_JAX_04 = __import__("jax").__version__.startswith("0.4.")
 
 
 def run_script(body: str, devices: int = 8, timeout: int = 420) -> str:
@@ -27,7 +20,6 @@ def run_script(body: str, devices: int = 8, timeout: int = 420) -> str:
         "import os\n"
         f"os.environ['XLA_FLAGS'] = "
         f"'--xla_force_host_platform_device_count={devices}'\n"
-        "import repro.utils.compat\n"  # jax.shard_map/set_mesh on old jax
         + textwrap.dedent(body)
     )
     env = dict(os.environ)
@@ -67,14 +59,6 @@ def test_sharded_engine_matches_host():
     assert "ENGINE_OK" in out
 
 
-@pytest.mark.xfail(
-    reason="jax 0.4.x GSPMD divergence in the DP+TP step: measured loss "
-           "6.1623 (single-device) vs 6.1985 (2x4 mesh) on jax 0.4.37 — a "
-           "0.59% relative gap, 36x the 1e-3 tolerance, with "
-           "compute_dtype=float32, so this is a real lowering difference "
-           "and not reduction-order noise; do NOT widen the tolerance to "
-           "mask it.  Passes on jax>=0.5.",
-    condition=_JAX_04, strict=True)
 def test_dp_tp_train_step_matches_single_device():
     out = run_script("""
         import dataclasses, jax, numpy as np, jax.numpy as jnp
@@ -117,15 +101,6 @@ def test_dp_tp_train_step_matches_single_device():
     assert "DPTP_OK" in out
 
 
-@pytest.mark.xfail(
-    reason="jax 0.4.x shard_map rep-check: pipelined_loss returns a "
-           "replicated P() scalar that only check_vma=False (jax>=0.5) can "
-           "express; the 0.4.x compat shim (utils/compat.py) must run "
-           "checked, so _SpecError fires at trace time "
-           "(ShapedArray(float32[]) fails rep inference).  No cheap 0.4.x "
-           "workaround: it would need pipelined_loss to prove replication "
-           "via an explicit collective on every output.  Needs jax>=0.5.",
-    condition=_JAX_04, strict=True)
 def test_pipeline_parallel_matches_dense():
     out = run_script("""
         import dataclasses, jax, numpy as np, jax.numpy as jnp

@@ -193,6 +193,94 @@ def test_profile_mode_resumes_exact():
     assert got.stats["resumes"] > 0
 
 
+def test_grow_caps_to_observed_need():
+    """An overflow grows the step to the power of two that holds what the
+    dispatch counted (at least doubling, at most max_cap), and later steps
+    stay monotone."""
+    from repro.core.exec import _grow_caps
+
+    assert _grow_caps([8, 8, 16], 1, 1 << 10, need=100) == [8, 128, 128]
+    assert _grow_caps([8, 8], 0, 1 << 10, need=3) == [16, 16]
+    assert _grow_caps([8, 8], 0, 1 << 10) == [16, 16]
+    assert _grow_caps([8, 8], 0, 64, need=1000) == [64, 64]
+    with pytest.raises(RuntimeError, match="max capacity"):
+        _grow_caps([64], 0, 64, need=1000)
+
+
+@pytest.mark.parametrize("profile", [False, True])
+def test_overflow_resumes_once_per_step(profile):
+    """With one chunk, each step overflows at most once: the first
+    overflow grows its capacity to the whole expansion it counted."""
+    rng = np.random.default_rng(3)
+    g = random_labeled_graph(rng, n_vertices=40, p_edge=0.5)
+    q = random_query_graph(rng, g, n_qv=3, with_labels=False, with_id=False)
+    want = Executor(g, ExecOpts()).run(build_plan(g, q))
+    got = Executor(g, ExecOpts(init_cap=8, chunk=64)).run(
+        _tiny_plan(g, q), profile=profile)
+    assert _multiset(got, len(q.pvars)) == _multiset(want, len(q.pvars))
+    assert got.stats["resumes"] > 0
+    assert max(got.stats["step_retries"]) == 1
+
+
+def test_small_probe_keeps_pipeline_for_plans_that_are_not_small(
+        lubm_graph, monkeypatch):
+    """A plan shortlisted by a wrong estimate runs once: its first run
+    shows it is not small, so no legacy run is timed against it."""
+    import repro.core.exec as ex
+    from repro.core import build_query_graph
+    from repro.rdf.sparql import parse_sparql
+    from repro.rdf.workloads import LUBM_QUERIES
+
+    g, maps = lubm_graph
+    q = build_query_graph(parse_sparql(LUBM_QUERIES["Q2"]).where.triples, maps)
+    plan = build_plan(g, q)
+    exe = Executor(g, ExecOpts())
+    monkeypatch.setattr(ex, "_SMALL_PLAN_ROWS", max(1.0, plan.estimated_rows()))
+    assert ex._small_plan(plan, exe.opts)
+    runs = []
+    real = exe._run_impl
+    monkeypatch.setattr(exe, "_run_impl",
+                        lambda *a, **kw: runs.append(a[-1]) or real(*a, **kw))
+    res = exe.run(plan)
+    assert res.count == len(enumerate_matches(g, q)) > 4
+    assert "small_probe" not in res.stats
+    assert exe._small_mode[plan.signature()] is False
+    assert runs == [None, exe.opts]  # the served call and one pipelined run
+
+
+def _step_spans(trace: dict) -> list[dict]:
+    out, todo = [], [trace["root"]]
+    while todo:
+        span = todo.pop()
+        if span.get("name") == "step":
+            out.append(span["meta"])
+        todo.extend(reversed(span.get("children", ())))
+    return sorted(out, key=lambda m: m["step"])
+
+
+@pytest.mark.parametrize("mode", ["", "pallas_interpret"])
+def test_step_spans_name_the_traced_kernels(lubm_graph, monkeypatch, mode):
+    """Step spans carry what each step's program was traced with, and its
+    implementation; the expansion kernel is the one ``kernel`` names."""
+    from repro.core import SparqlEngine
+    from repro.kernels import ops
+    from repro.rdf.workloads import LUBM_QUERIES
+
+    g, maps = lubm_graph
+    monkeypatch.setenv("REPRO_KERNELS", mode)
+    res = SparqlEngine(g, maps, ExecOpts()).query(LUBM_QUERIES["Q2"],
+                                                  trace=True)
+    steps = _step_spans(res.stats["trace"])
+    assert [m["kernel"] for m in steps] == ["expand_filter", "ragged_expand"]
+    impl = ops.PALLAS_INTERPRET if mode else ops.XLA
+    assert steps[0]["kernels"] == ["expand_filter:xla"]
+    assert steps[1]["kernels"][:3] == [
+        "ragged_expand:xla", f"bitmap_superset:{impl}",
+        "signature_filter:xla"]
+    base = res.stats["exec"]["branches"][0]["base"]
+    assert base["step_kernels"] == ["expand_filter", "ragged_expand"]
+
+
 # --------------------------------------------------- engine-level coverage
 def test_engine_optional_under_tiny_caps(lubm_graph):
     g, maps = lubm_graph

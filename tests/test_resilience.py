@@ -202,6 +202,30 @@ def test_ladder_exhaustion_reraises(lubm_graph):
     assert snap["max_level"] == MAX_LEVEL
 
 
+def test_tpu_compile_refusal_raises_without_degrading(lubm_graph,
+                                                     monkeypatch):
+    """A kernel the TPU compiler refuses (Mosaic lowering, VMEM overflow)
+    fails the query: no retry, no escalation to a config that avoids it."""
+    vmem = RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in memory "
+                        "space vmem while allocating on stack")
+    mosaic = RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: "
+                          "Only 2D gather is supported")
+    assert not is_transient_fault(vmem)
+    assert not is_transient_fault(mosaic)
+    g, maps = lubm_graph
+    eng = SparqlEngine(g, maps)
+
+    def refuse(*args, **kwargs):
+        raise vmem
+
+    monkeypatch.setattr(eng.executor, "_run_impl", refuse)
+    with pytest.raises(RuntimeError, match="memory space vmem"):
+        eng.query(Q_ADVISOR)
+    snap = eng.executor.resilience_snapshot()
+    assert snap["fault_retries"] == 0 and snap["escalations"] == 0
+    assert snap["degraded_plans"] == 0
+
+
 def test_nontransient_errors_bypass_ladder(lubm_graph):
     g, maps = lubm_graph
     eng = SparqlEngine(g, maps)
