@@ -61,6 +61,7 @@ from repro.core.planner import ExecPlan, Step
 from repro.core.planner.ir import _next_pow2
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.obs.trace import maybe_span
 from repro.rdf.graph import LabeledGraph
 from repro.resilience import faults as _faults
 from repro.resilience.cancel import CancelToken, QueryCancelled
@@ -872,14 +873,15 @@ def _annotate_step_spans(trace, plan: ExecPlan, dg: DeviceGraph,
                          kernels: dict[int, list[str]], stats: dict,
                          n_src: int) -> None:
     """Attach one summary span per plan step: executed-counter meta
-    (rows/kept/retries/capacity), the kernels its programs were traced with
-    (``kernels``, as ``name:impl``), and a roofline estimate next to the
-    measured wall time where the device has published peaks (profiled runs
-    only have real per-step durations; sampled traces report zero-duration
-    spans)."""
+    (rows/kept/retries/capacity) and the kernels its programs were traced
+    with (``kernels``, as ``name:impl``).  Only profiled runs have real
+    per-step durations, so only they carry a roofline estimate next to the
+    measured wall time, where the device has published peaks; sampled
+    traces report zero-duration spans of counters."""
     from repro.analysis.roofline import estimate_step_ms
 
-    device_kind = jax.devices()[0].device_kind
+    profiled = trace.profile_steps
+    device_kind = jax.devices()[0].device_kind if profiled else None
     nq = plan.query.n_vertices
     bitmap_words = int(dg.arrays["label_bitmap"].shape[1])
     wall = stats.get("step_wall_ms")
@@ -907,7 +909,7 @@ def _annotate_step_spans(trace, plan: ExecPlan, dg: DeviceGraph,
         est = estimate_step_ms(
             kernel, device_kind, expanded=expanded, rows=rows_in,
             capacity=cap, nq=nq, bitmap_words=bitmap_words,
-            n_iters=dg.max_log_deg)
+            n_iters=dg.max_log_deg) if profiled else None
         if est is not None:
             model_ms = est["model_ms"]
             for _ in step.nontree:
@@ -1245,7 +1247,9 @@ class Executor:
         ``trace`` (a :class:`repro.obs.Trace`) records compile / dispatch /
         device-wait / per-step spans under the caller's current span; a
         trace with ``profile_steps=True`` forces profiled execution so the
-        step spans carry real device wall times.  ``params`` supplies a
+        step spans carry real device wall times.  A parameterized run
+        (``params`` given) records no per-step spans, and executes exactly
+        as an untraced one (small-plan probe included).  ``params`` supplies a
         parameterized plan's constant vector (int32 ``[plan.n_params]``);
         a negative entry means the constant is absent from the dictionary
         and short-circuits to an empty result.  ``cancel`` (a
@@ -1356,7 +1360,11 @@ class Executor:
                               _empty_p(plan), np.zeros(0, np.int32))
         opts = self.opts if _opts_override is None else _opts_override
         small_legacy = False  # remembered small-probe verdict applied?
-        if (_opts_override is None and initial is None and trace is None
+        # a run whose trace records step spans stays out of the probe, which
+        # answers from runs the trace does not see; a parameterized run
+        # records none
+        steps_traced = trace is not None and params is None
+        if (_opts_override is None and initial is None and not steps_traced
                 and not profile and _small_plan(plan, opts)):
             # B1-class small queries: the pipelined machinery's fixed
             # overhead (per-step capacity schedule, fused-kernel setup,
@@ -1633,7 +1641,7 @@ class Executor:
         # profiler's kernel-mix accounting
         stats["step_kernels"] = [_expansion_kernel(kernels.get(si, []))
                                  for si in range(n_steps)]
-        if trace is not None and n_steps:
+        if steps_traced and n_steps:
             _annotate_step_spans(trace, plan, dg, kernels, stats, n_src)
         bindings = (np.concatenate(out_b) if out_b else _empty(plan)) \
             if collect == "bindings" else None
@@ -1647,7 +1655,8 @@ class Executor:
     def run_batch(self, plan: ExecPlan, params_mat: np.ndarray,
                   collect: str = "bindings",
                   state: tuple | None = None,
-                  cancel: CancelToken | None = None) -> list[Result]:
+                  cancel: CancelToken | None = None,
+                  trace=None) -> list[Result]:
         """Answer ``B`` same-shape queries in one device launch.
 
         ``params_mat`` (int32 ``[B, plan.n_params]``) stacks one constant
@@ -1664,7 +1673,9 @@ class Executor:
         empty results without touching the device.  Falls back to
         sequential :meth:`run` calls when the plan's start set does not fit
         one chunk.  The fused Pallas kernel is disabled under vmap — the
-        ref/jnp path is batchable on every backend."""
+        ref/jnp path is batchable on every backend.  ``trace`` records the
+        same compile / dispatch / device-wait spans as :meth:`run`, and no
+        per-step spans."""
         state = self.pin() if state is None else state
         view, dg = state
         params_mat = np.asarray(params_mat, np.int32)
@@ -1680,14 +1691,16 @@ class Executor:
                           _empty(plan) if collect == "bindings" else None,
                           _empty_p(plan), np.zeros(0, np.int32))
 
+        def solo(i: int) -> Result:
+            return self.run(plan, collect=collect, state=state,
+                            params=params_mat[i], cancel=cancel, trace=trace)
+
         results: list[Result | None] = [None] * B
         if plan.unsat:
             return [empty() for _ in range(B)]
         if not plan.steps or plan.n_params == 0 or B == 1:
             # degenerate shapes: nothing to amortize, reuse the single path
-            return [self.run(plan, collect=collect, state=state,
-                             params=params_mat[i], cancel=cancel)
-                    for i in range(B)]
+            return [solo(i) for i in range(B)]
 
         opts = replace(self.opts, use_fused=False, async_chunks=1)
         per_lane_start = plan.start_param_slot >= 0
@@ -1714,9 +1727,7 @@ class Executor:
             if n_src > opts.chunk:
                 # multi-chunk start sets: per-lane accumulation across
                 # chunks loses the one-launch win anyway — run sequentially
-                return [self.run(plan, collect=collect, state=state,
-                                 params=params_mat[i], cancel=cancel)
-                        for i in range(B)]
+                return [solo(i) for i in range(B)]
             chunk_size = n_src
             for i in range(B):
                 if (params_mat[i] < 0).any():
@@ -1750,7 +1761,8 @@ class Executor:
         key = ("batch", plan.signature(), used, chunk_size, L_pad,
                per_lane_start, collect, opts.key(), dg.key())
         fn = self._compiled.get(key)
-        if fn is None:
+        fresh = fn is None
+        if fresh:
             raw = build_chunk_fn(dg, plan, used, chunk_size, opts,
                                  table_input=False, collect=collect,
                                  start_step=0, stop_step=n_steps)
@@ -1773,18 +1785,20 @@ class Executor:
                 f"query cancelled: {cancel.reason or 'cancelled'}")
         try:
             poison = _faults.fire("dispatch")
-            (b, p, org, count, ovf_step, totals, kepts, pins,
-             pouts) = fn(chunk_in, count_in, p0, o0, pmat, sarrs, dg.arrays)
+            with maybe_span(trace, "compile" if fresh else "dispatch",
+                            lanes=L_pad):
+                (b, p, org, count, ovf_step, totals, kepts, pins,
+                 pouts) = fn(chunk_in, count_in, p0, o0, pmat, sarrs,
+                             dg.arrays)
         except Exception as e:  # noqa: BLE001 - filtered just below
             if not is_transient_fault(e):
                 raise
             # batched dispatch hit memory pressure: fall back to the
             # sequential path, whose per-run ladder absorbs the fault
-            return [results[i] if results[i] is not None
-                    else self.run(plan, collect=collect, state=state,
-                                  params=params_mat[i], cancel=cancel)
+            return [results[i] if results[i] is not None else solo(i)
                     for i in range(B)]
-        count_h = np.asarray(count)
+        with maybe_span(trace, "device_wait"):
+            count_h = np.asarray(count)
         if poison:
             count_h = np.zeros_like(count_h)
         ovf_h = np.asarray(ovf_step)
@@ -1803,8 +1817,7 @@ class Executor:
                 # overflowing lane: redo it alone — run()'s suffix-resume
                 # growth is deterministic, so the answer is identical to
                 # a lane that had fit
-                results[qi] = self.run(plan, collect=collect, state=state,
-                                       params=params_mat[qi], cancel=cancel)
+                results[qi] = solo(qi)
                 continue
             c = int(count_h[li])
             stats = _empty_stats(n_steps)

@@ -26,7 +26,6 @@ per engine, and results are renamed back to the caller's variables.
 
 from __future__ import annotations
 
-import contextlib
 import re as _re
 import threading
 from dataclasses import dataclass, field
@@ -35,6 +34,7 @@ import numpy as np
 
 from repro.core.exec import ExecOpts, Executor, Result
 from repro.core.planner import ExecPlan, build_plan, explain_plan, np_cmp
+from repro.obs.trace import maybe_span
 from repro.obs.workload import qerror
 from repro.core.query import QueryGraph, build_query_graph
 from repro.resilience.cancel import CancelToken, QueryCancelled
@@ -44,14 +44,6 @@ from repro.rdf.transform import TransformMaps
 from repro.utils import get_logger
 
 log = get_logger("core.sparql")
-
-_NULL_CM = contextlib.nullcontext()
-
-
-def _maybe_span(trace, name: str, **meta):
-    """A trace span when tracing is on, else a shared no-op context."""
-    return trace.span(name, **meta) if trace is not None else _NULL_CM
-
 
 def _as_trace(trace):
     """Normalize the public ``trace`` argument: False/None → off, True →
@@ -296,11 +288,11 @@ class SparqlEngine:
         from repro.serve.fingerprint import canonicalize_query
 
         if isinstance(source, str):
-            with _maybe_span(trace, "parse"):
+            with maybe_span(trace, "parse"):
                 ast = parse_sparql(source)
         else:
             ast = source
-        with _maybe_span(trace, "fingerprint"):
+        with maybe_span(trace, "fingerprint"):
             canon = canonicalize_query(ast)
         return self.compile_canonical(canon, trace=trace), canon
 
@@ -318,7 +310,7 @@ class SparqlEngine:
         if trace is not None:
             trace.event("plan_cache", hit=not fresh)
         if fresh:
-            with _maybe_span(trace, "plan_search") as sp:
+            with maybe_span(trace, "plan_search") as sp:
                 compiled = self._compile_ast(canon.query, canon.fingerprint)
                 if trace is not None:
                     sp.meta.update(
@@ -370,7 +362,7 @@ class SparqlEngine:
 
         param_ids = {id(t): k
                      for k, t in enumerate(iter_param_occurrences(g))}
-        with _maybe_span(trace, "plan_search"):
+        with maybe_span(trace, "plan_search"):
             q = build_query_graph(g.triples, self.maps, param_ids=param_ids)
             if q.param_missing:
                 # the representative's constant is missing — other members
@@ -426,16 +418,18 @@ class SparqlEngine:
                       cancel: CancelToken | None = None) -> QueryResult:
         """Run one family member: resolve its constant vector and execute
         the shared parameterized plan.  Result columns carry the shape's
-        canonical variable names (callers rename back)."""
+        canonical variable names (callers rename back).  ``trace`` records
+        the executor's compile / dispatch / device-wait spans and the host
+        work after it, under the caller's current span; the parameterized
+        path records no per-step spans."""
         params = self.resolve_params(consts)
         executor = self.executor
         state = executor.pin()
         count_only = (collect == "count" and not family.expensive
                       and not family.has_modifiers)
-        with _maybe_span(trace, "execute", branches=1):
-            res = executor.run(
-                family.plan, collect="count" if count_only else "bindings",
-                state=state, trace=trace, params=params, cancel=cancel)
+        res = executor.run(
+            family.plan, collect="count" if count_only else "bindings",
+            state=state, trace=trace, params=params, cancel=cancel)
         if count_only:
             return QueryResult(
                 list(family.variables),
@@ -443,20 +437,23 @@ class SparqlEngine:
                 list(family.kinds), count=res.count,
                 stats={"plan_ms": family.plan_ms,
                        "exec": {"branches": [{"base": res.stats}]}})
-        return self._finish_param(family, res)
+        with maybe_span(trace, "host_ops"):
+            return self._finish_param(family, res)
 
     def execute_param_batch(self, family: ParamFamily, const_rows,
                             collect: str = "bindings",
                             cancel: CancelToken | None = None,
-                            ) -> list[QueryResult]:
+                            trace=None) -> list[QueryResult]:
         """Answer ``B`` members of one family in a single vmapped device
         launch (:meth:`Executor.run_batch`); each result is bit-identical
-        to what per-member :meth:`execute_param` would return."""
+        to what per-member :meth:`execute_param` would return.  ``trace``
+        (the batch leader's) records the launch as :meth:`execute_param`
+        records one member's."""
         if not const_rows:
             return []
         if len(const_rows) == 1:
             return [self.execute_param(family, const_rows[0], collect,
-                                       cancel=cancel)]
+                                       trace=trace, cancel=cancel)]
         executor = self.executor
         state = executor.pin()
         mat = np.stack([self.resolve_params(c) for c in const_rows])
@@ -464,19 +461,17 @@ class SparqlEngine:
                       and not family.has_modifiers)
         results = executor.run_batch(
             family.plan, mat, collect="count" if count_only else "bindings",
-            state=state, cancel=cancel)
-        out: list[QueryResult] = []
-        for res in results:
-            if count_only:
-                out.append(QueryResult(
-                    list(family.variables),
-                    np.zeros((0, len(family.variables)), np.int32),
-                    list(family.kinds), count=res.count,
-                    stats={"plan_ms": family.plan_ms,
-                           "exec": {"branches": [{"base": res.stats}]}}))
-            else:
-                out.append(self._finish_param(family, res))
-        return out
+            state=state, cancel=cancel, trace=trace)
+        if count_only:
+            return [QueryResult(
+                list(family.variables),
+                np.zeros((0, len(family.variables)), np.int32),
+                list(family.kinds), count=res.count,
+                stats={"plan_ms": family.plan_ms,
+                       "exec": {"branches": [{"base": res.stats}]}})
+                for res in results]
+        with maybe_span(trace, "host_ops"):
+            return [self._finish_param(family, res) for res in results]
 
     def _finish_param(self, family: ParamFamily, res: Result) -> QueryResult:
         """Post-executor finish for one family member: post-hoc filters,
@@ -551,12 +546,12 @@ class SparqlEngine:
         # object itself must be captured too, not re-read per branch
         executor = self.executor
         state = executor.pin()
-        with _maybe_span(trace, "execute", branches=len(compiled.branches)):
+        with maybe_span(trace, "execute", branches=len(compiled.branches)):
             for bi, br in enumerate(compiled.branches):
                 if cancel is not None:
                     cancel.check({"exec": {"branches": exec_stats}})
                 try:
-                    with _maybe_span(trace, "branch", index=bi):
+                    with maybe_span(trace, "branch", index=bi):
                         rows, count, info = self._exec_branch(
                             br, collect if not modifiers else "bindings",
                             profile, executor, state, trace, cancel)
@@ -577,18 +572,19 @@ class SparqlEngine:
                     if br.variables != variables:
                         rows = _align_columns(rows, br.variables, variables)
                     all_rows.append(rows)
-            rows = (np.concatenate(all_rows) if all_rows
-                    else np.zeros((0, 0), np.int32))
-            if modifiers:
-                if compiled.distinct:
-                    rows = np.unique(rows, axis=0)
-                if compiled.offset:
-                    rows = rows[compiled.offset:]
-                if compiled.limit is not None:
-                    rows = rows[: compiled.limit]
-                total = int(rows.shape[0])
-            elif collect == "bindings":
-                total = int(rows.shape[0])
+            with maybe_span(trace, "host_ops"):
+                rows = (np.concatenate(all_rows) if all_rows
+                        else np.zeros((0, 0), np.int32))
+                if modifiers:
+                    if compiled.distinct:
+                        rows = np.unique(rows, axis=0)
+                    if compiled.offset:
+                        rows = rows[compiled.offset:]
+                    if compiled.limit is not None:
+                        rows = rows[: compiled.limit]
+                    total = int(rows.shape[0])
+                elif collect == "bindings":
+                    total = int(rows.shape[0])
         return QueryResult(list(variables), rows, list(kinds),
                            count=total,
                            stats={"plan_ms": compiled.plan_ms,
@@ -805,12 +801,13 @@ class SparqlEngine:
         info: dict = {"base": res.stats}
         if count_only:
             return None, res.count, info
-        table, ptable, _ = self._apply_expensive(res.bindings,
-                                                 res.pvar_bindings,
-                                                 br.q, br.expensive)
+        with maybe_span(trace, "host_ops"):
+            table, ptable, _ = self._apply_expensive(res.bindings,
+                                                     res.pvar_bindings,
+                                                     br.q, br.expensive)
         opt_stats: list[dict] = []
         for oi, co in enumerate(br.optionals):
-            with _maybe_span(trace, "optional", index=oi):
+            with maybe_span(trace, "optional", index=oi):
                 table, ptable, ost = self._exec_left_join(table, ptable, co,
                                                           profile, executor,
                                                           state, trace,
@@ -819,16 +816,17 @@ class SparqlEngine:
         if opt_stats:
             info["optionals"] = opt_stats
         q_all = br.q_all
-        cols: list[np.ndarray] = []
-        for var in br.variables:
-            if var in q_all.var_to_vertex:
-                cols.append(table[:, q_all.var_to_vertex[var]])
-            elif var in q_all.pvars:
-                cols.append(ptable[:, q_all.pvars.index(var)])
-            else:
-                cols.append(np.full(table.shape[0], -1, np.int32))
-        rows = np.stack(cols, axis=1) if cols else np.zeros(
-            (table.shape[0], 0), np.int32)
+        with maybe_span(trace, "host_ops"):
+            cols: list[np.ndarray] = []
+            for var in br.variables:
+                if var in q_all.var_to_vertex:
+                    cols.append(table[:, q_all.var_to_vertex[var]])
+                elif var in q_all.pvars:
+                    cols.append(ptable[:, q_all.pvars.index(var)])
+                else:
+                    cols.append(np.full(table.shape[0], -1, np.int32))
+            rows = np.stack(cols, axis=1) if cols else np.zeros(
+                (table.shape[0], 0), np.int32)
         return rows, int(rows.shape[0]), info
 
     # ----------------------------------------------------------- internals
@@ -872,21 +870,22 @@ class SparqlEngine:
             matched = executor.run(plan, initial=(b0, p0, org0),
                                    profile=profile, state=state, trace=trace,
                                    cancel=cancel)
-        mt, mp, morg = self._apply_expensive(matched.bindings,
-                                             matched.pvar_bindings,
-                                             q_ext, expensive,
-                                             origins=matched.origins)
-        # rows with no optional match: keep base + nulls
-        has_match = np.zeros(table.shape[0], dtype=bool)
-        if morg.shape[0]:
-            has_match[morg] = True
-        unmatched = np.flatnonzero(~has_match)
-        un_b = np.full((unmatched.shape[0], nq_ext), -1, dtype=np.int32)
-        un_b[:, : table.shape[1]] = table[unmatched]
-        un_p = np.full((unmatched.shape[0], mp.shape[1]), -1, np.int32)
-        un_p[:, : ptable.shape[1]] = ptable[unmatched]
-        new_table = np.concatenate([mt, un_b], axis=0)
-        new_ptable = np.concatenate([mp, un_p], axis=0)
+        with maybe_span(trace, "host_ops"):
+            mt, mp, morg = self._apply_expensive(matched.bindings,
+                                                 matched.pvar_bindings,
+                                                 q_ext, expensive,
+                                                 origins=matched.origins)
+            # rows with no optional match: keep base + nulls
+            has_match = np.zeros(table.shape[0], dtype=bool)
+            if morg.shape[0]:
+                has_match[morg] = True
+            unmatched = np.flatnonzero(~has_match)
+            un_b = np.full((unmatched.shape[0], nq_ext), -1, dtype=np.int32)
+            un_b[:, : table.shape[1]] = table[unmatched]
+            un_p = np.full((unmatched.shape[0], mp.shape[1]), -1, np.int32)
+            un_p[:, : ptable.shape[1]] = ptable[unmatched]
+            new_table = np.concatenate([mt, un_b], axis=0)
+            new_ptable = np.concatenate([mp, un_p], axis=0)
         return new_table, new_ptable, matched.stats
 
     def _apply_expensive(self, table, ptable, q: QueryGraph, filters,

@@ -15,10 +15,10 @@ entries, and bench traces into one report.
 """
 
 from repro.obs.slowlog import SlowQueryLog
-from repro.obs.trace import Span, Trace, chrome_trace
+from repro.obs.trace import Span, Trace, chrome_trace, maybe_span
 from repro.obs.workload import (DecisionJournal, WorkloadProfile,
                                 WorkloadProfiler, qerror, qerror_log10)
 
-__all__ = ["Span", "Trace", "SlowQueryLog", "chrome_trace",
+__all__ = ["Span", "Trace", "SlowQueryLog", "chrome_trace", "maybe_span",
            "WorkloadProfile", "WorkloadProfiler", "DecisionJournal",
            "qerror", "qerror_log10"]

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any
+from typing import Any, Callable
 
 from repro.obs.trace import Trace, chrome_trace
 
@@ -41,26 +41,30 @@ class SlowQueryLog:
         return self.capacity > 0
 
     def record(self, fingerprint: str, wall_ms: float, trace: Trace,
+               explain: Callable[[], dict] | dict | None = None,
                **extra: Any) -> bool:
-        """Offer one finished execution; returns True if it was kept."""
+        """Offer one finished execution; returns True if it was kept.
+        ``explain`` may be a callable: it is called only for an entry the
+        log admits, so a rejected execution never builds its plan
+        description."""
         if not self.enabled:
             return False
-        entry = {"id": trace.trace_id, "fingerprint": fingerprint,
-                 "wall_ms": round(float(wall_ms), 3),
-                 "recorded_at": time.time(), "trace": trace, **extra}
         with self._lock:
             prev = self._by_fp.get(fingerprint)
             if prev is not None:
                 if wall_ms <= prev["wall_ms"]:
                     return False
-                self._by_fp[fingerprint] = entry
-                return True
-            if len(self._by_fp) >= self.capacity:
+            elif len(self._by_fp) >= self.capacity:
                 fastest = min(self._by_fp.values(),
                               key=lambda e: e["wall_ms"])
                 if wall_ms <= fastest["wall_ms"]:
                     return False
                 del self._by_fp[fastest["fingerprint"]]
+            entry = {"id": trace.trace_id, "fingerprint": fingerprint,
+                     "wall_ms": round(float(wall_ms), 3),
+                     "recorded_at": time.time(), "trace": trace, **extra}
+            if explain is not None:
+                entry["explain"] = explain() if callable(explain) else explain
             self._by_fp[fingerprint] = entry
             return True
 

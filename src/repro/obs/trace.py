@@ -8,14 +8,20 @@ Spans form a tree; timestamps are seconds relative to the trace origin
 are meaningful).
 
 Span tree construction is stack-based: ``with trace.span("execute"): ...``
-nests everything opened inside under it.  Spans may also be attached
-post-hoc with a known duration (``trace.add``) — the executor uses that to
-report per-step device wall times measured by its profiled path — or as
-zero-duration events (``trace.event``).
+nests everything opened inside under it.  Such a span also records the
+thread's CPU seconds (``time.thread_time``) over its interval — for pure
+Python work, wall minus CPU is time spent waiting for the interpreter lock
+or the OS — and opens a ``jax.profiler.TraceAnnotation`` named
+``repro/<span>``, so the span lands in a profiler trace on the device
+trace's clock.  Spans may also be attached post-hoc with a known duration
+(``trace.add``) — the executor uses that to report per-step device wall
+times measured by its profiled path — or as zero-duration events
+(``trace.event``); those carry no CPU figure.
 
 A trace is *not* generally thread-safe; the serving layer hands it from
-the submitting thread to the scheduler worker sequentially (parse spans
-finish before the flight is enqueued), which is safe.
+the HTTP handler to the submitting thread to the scheduler worker and back
+sequentially (each stage's spans finish before the next stage starts),
+which is safe.
 """
 
 from __future__ import annotations
@@ -26,19 +32,30 @@ import json
 import time
 from typing import Any, Iterator
 
+import jax
+
 _ids = itertools.count(1)
+_OFF = contextlib.nullcontext()
+
+
+def maybe_span(trace: "Trace | None", name: str, **meta: Any):
+    """``trace.span(name, **meta)``, or a shared no-op context for an
+    untraced request (``trace is None``): one pointer compare."""
+    return _OFF if trace is None else trace.span(name, **meta)
 
 
 class Span:
     """One node of the span tree.  ``t0``/``dur`` are seconds relative to
-    the owning trace's origin."""
+    the owning trace's origin; ``cpu`` is the thread's CPU seconds over the
+    span (``None`` for post-hoc spans)."""
 
-    __slots__ = ("name", "t0", "dur", "meta", "children")
+    __slots__ = ("name", "t0", "dur", "cpu", "meta", "children")
 
     def __init__(self, name: str, t0: float, meta: dict | None = None):
         self.name = name
         self.t0 = t0
         self.dur = 0.0
+        self.cpu: float | None = None
         self.meta: dict[str, Any] = meta if meta is not None else {}
         self.children: list[Span] = []
 
@@ -46,6 +63,8 @@ class Span:
         d: dict[str, Any] = {"name": self.name,
                              "t0_ms": round(self.t0 * 1e3, 4),
                              "dur_ms": round(self.dur * 1e3, 4)}
+        if self.cpu is not None:
+            d["cpu_ms"] = round(self.cpu * 1e3, 4)
         if self.meta:
             d["meta"] = self.meta
         if self.children:
@@ -95,9 +114,13 @@ class Trace:
         parent = self._stack[-1]
         parent.children.append(s)
         self._stack.append(s)
+        labels = {} if self.query_id is None else {"query_id": self.query_id}
+        cpu0 = time.thread_time()
         try:
-            yield s
+            with jax.profiler.TraceAnnotation(f"repro/{name}", **labels):
+                yield s
         finally:
+            s.cpu = time.thread_time() - cpu0
             s.dur = self._now() - s.t0
             self._stack.pop()
 
@@ -122,6 +145,10 @@ class Trace:
     @property
     def dur_ms(self) -> float:
         return self.root.dur * 1e3
+
+    def elapsed_ms(self) -> float:
+        """Milliseconds since the trace began, finished or not."""
+        return self._now() * 1e3
 
     def span_sum_ms(self) -> float:
         """Sum of top-level child durations — the accounted-for share of
@@ -160,6 +187,8 @@ def _chrome_events(span: Span, pid: int, tid: int, out: list[dict]) -> None:
     args = {k: (v if isinstance(v, (int, float, str, bool, type(None)))
                 else repr(v))
             for k, v in (span.meta or {}).items()}
+    if span.cpu is not None:
+        args["cpu_ms"] = round(span.cpu * 1e3, 4)
     out.append({"name": span.name, "ph": "X", "pid": pid, "tid": tid,
                 "ts": round(span.t0 * 1e6, 3),
                 "dur": round(span.dur * 1e6, 3), "args": args})

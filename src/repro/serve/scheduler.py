@@ -15,9 +15,10 @@ queued peers and answers the whole batch in one vmapped device launch via
 ``registry.execute_canonical_batch`` — splitting results back per request.
 A ``batch_window_ms`` micro-deadline optionally holds a lone eligible
 flight briefly to let peers arrive.  Forced-trace flights never coalesce
-or batch (each requester wants *their* execution observed), but their
-traces carry a ``batch_assemble`` span so batched and solo timelines stay
-comparable.
+or batch (each requester wants *their* execution observed); a sampled
+trace follows its flight as it is, and records how it was answered
+(``queue_wait`` from the flight's own times, then the registry's spans, or
+a coalesced waiter's ``execute`` span naming the flight it joined).
 
 Admission control bounds the number of queued flights (excess submissions
 fail fast with :class:`Overloaded`) and every request carries a deadline:
@@ -27,7 +28,6 @@ its deadline is dropped without executing.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import queue
 import threading
@@ -36,6 +36,7 @@ import uuid
 from dataclasses import dataclass, field
 
 from repro.core.sparql_exec import QueryResult
+from repro.obs.trace import maybe_span
 from repro.rdf.sparql import SelectQuery, parse_sparql
 from repro.resilience.cancel import CancelToken, QueryCancelled
 from repro.serve.fingerprint import (CanonicalQuery, ParamQuery,
@@ -46,15 +47,12 @@ from repro.utils import get_logger
 log = get_logger("serve.scheduler")
 
 
-def _maybe_span(trace, name: str, **meta):
-    return (trace.span(name, **meta) if trace is not None
-            else contextlib.nullcontext())
-
-
 # correlation ids: one per *flight* (coalesced waiters share their leader's
-# id — the id names the execution, not the HTTP request).  A short random
-# process prefix keeps ids from different server processes distinguishable
-# in merged logs.
+# id — the id names the execution, not the HTTP request).  A traced request
+# takes its id as it is submitted, so its first spans carry it; a flight it
+# creates adopts that id, and if it coalesces instead, its trace keeps its
+# own id and names the flight's.  A short random process prefix keeps ids
+# from different server processes distinguishable in merged logs.
 _qid_prefix = uuid.uuid4().hex[:6]
 _qid_counter = itertools.count(1)
 
@@ -111,7 +109,7 @@ class _Flight:
     result: QueryResult | None = None
     error: Exception | None = None
     waiters: int = 1
-    trace: object | None = None  # repro.obs.Trace for forced-trace requests
+    trace: object | None = None  # the creating request's repro.obs.Trace
     query_id: str = ""  # correlation id, threaded through traces/logs/journal
     # same-shape batching: the parameterized form (None = batching-
     # ineligible), the batch key (dataset, shape, version), and whether a
@@ -180,6 +178,7 @@ class Scheduler:
         self._reg_accepts_qid = _accepts(reg_exec, "query_id")
         self._batch_accepts_cancel = _accepts(reg_batch, "cancel")
         self._batch_accepts_qids = _accepts(reg_batch, "query_ids")
+        self._batch_accepts_traces = _accepts(reg_batch, "traces")
         # EMA of execution time, for the Overloaded Retry-After estimate
         self._ema_exec_ms = 50.0
         self._queue: queue.Queue = queue.Queue()
@@ -270,32 +269,56 @@ class Scheduler:
     # ------------------------------------------------------------- submit
     def submit(self, dataset: str, query: str | SelectQuery | CanonicalQuery,
                timeout_s: float | None = None,
-               trace: bool = False) -> QueryResult:
+               trace=None) -> QueryResult:
         """Execute (or join) a query; returns bindings with the caller's
         variable names.  Raises ``Overloaded`` / ``DeadlineExceeded`` /
         parse and plan errors from the engine.
 
-        ``trace=True`` forces a profiled :class:`repro.obs.Trace` for this
-        request: the result's ``stats["trace"]`` carries the span tree.
-        Forced-trace flights never coalesce (each requester wants *their*
-        execution observed), and parse/canonicalize happen inside the trace
-        so the span sum accounts for the submitting thread's work too."""
+        ``trace`` is this request's :class:`repro.obs.Trace`:
+
+        - ``None`` (default): sampled here at the registry's
+          ``trace_sample`` (``registry.sample_trace()``);
+        - ``False``: none (the caller has already decided);
+        - ``True``: a forced, profiled trace; the result's
+          ``stats["trace"]`` carries its span tree;
+        - a ``Trace``: the caller's own, forced when ``profile_steps`` is
+          set and sampled otherwise.
+
+        A trace made here is finished and recorded here; a caller's trace
+        is the caller's to finish and record.  The result's
+        ``stats["trace_obj"]`` is the live trace either way.  Forced-trace
+        flights never coalesce or batch (each requester wants *their*
+        execution observed); a sampled trace changes nothing about how its
+        flight runs.  Parse and fingerprint happen inside the trace, so the
+        span sum accounts for the submitting thread's work too."""
         if not self._running:
             raise SchedulerStopped("scheduler is not running; call start()")
         t0 = time.perf_counter()
-        t = None
-        if trace:
+        owned = trace is None or trace is True
+        if trace is True:
             from repro.obs import Trace
             t = Trace(profile_steps=True)
+        elif trace is None:
+            sample = getattr(self.registry, "sample_trace", None)
+            t = sample() if sample is not None else None
+        else:
+            t = None if trace is False else trace
+        forced = t is not None and t.profile_steps
+        if t is not None:
+            # the request's own id from its first span on; a new flight
+            # takes it, a coalesced waiter names the flight it joined
+            if t.query_id is None:
+                t.query_id = next_query_id()
+            t.dataset = dataset
         pq: ParamQuery | None = None
         if isinstance(query, CanonicalQuery):
             canon = query
         else:
             if isinstance(query, str):
-                with _maybe_span(t, "parse"):
+                with maybe_span(t, "parse"):
                     query = parse_sparql(query)
-            with _maybe_span(t, "fingerprint"):
-                if t is None and self._can_batch:
+            with maybe_span(t, "fingerprint"):
+                if not forced and self._can_batch:
                     # shape + constants in one pass (canonicalization is a
                     # sub-step of parameterization, so no duplicate work)
                     pq = parameterize_query(query)
@@ -308,7 +331,7 @@ class Scheduler:
         timeout = self.default_timeout_s if timeout_s is None else timeout_s
         deadline = time.monotonic() + timeout
         key = (dataset, canon.fingerprint, version)
-        if t is not None:
+        if forced:
             # unique tail: a forced trace must execute, never coalesce
             key = key + (("trace", t.trace_id),)
 
@@ -329,12 +352,10 @@ class Scheduler:
                         retry_after_s=self.retry_after_s())
                 flight = _Flight(key=key, dataset=dataset, canonical=canon,
                                  version=version, deadline=deadline, trace=t,
-                                 query_id=next_query_id(),
+                                 query_id=(next_query_id() if t is None
+                                           else t.query_id),
                                  cancel=CancelToken(deadline),
                                  t_submit=time.monotonic())
-                if t is not None:
-                    t.query_id = flight.query_id
-                    t.dataset = dataset
                 if pq is not None:
                     flight.param = pq
                     flight.bkey = (dataset, pq.shape, version)
@@ -346,7 +367,13 @@ class Scheduler:
         self.metrics.dataset_inflight.inc(dataset)
         self.metrics.queue_depth.set(self._queue.qsize())
         try:
+            t_wait = time.perf_counter()
             finished = flight.done.wait(max(0.0, deadline - time.monotonic()))
+            if coalesced and t is not None:
+                # a coalesced waiter's execution is the flight it joined;
+                # its thread only waited, so the span has no CPU figure
+                t.add("execute", time.perf_counter() - t_wait,
+                      coalesced_into=flight.query_id, shared=True)
             ms = (time.perf_counter() - t0) * 1e3
             if (isinstance(flight.error, QueryCancelled)
                     and time.monotonic() >= deadline):
@@ -373,6 +400,13 @@ class Scheduler:
             assert res is not None
             stats = dict(res.stats)
             stats["query_id"] = flight.query_id
+            if t is not None:
+                stats["trace_obj"] = t
+                if owned:
+                    t.finish()
+                    self._record_trace(t)
+                    if forced:
+                        stats["trace"] = t.to_dict()
             return QueryResult(canon.restore(res.variables), res.rows,
                                list(res.kinds), count=res.count,
                                stats=stats)
@@ -410,6 +444,20 @@ class Scheduler:
         with self._lock:
             self._finish_locked(flight, result=result, error=error)
 
+    @staticmethod
+    def _start(flight: _Flight, now: float) -> None:
+        """A worker picks ``flight`` up: its queue wait ends now."""
+        flight.t_start = now
+        t = flight.trace
+        if t is not None:
+            t.thread = threading.current_thread().name
+            t.add("queue_wait", now - flight.t_submit)
+
+    def _record_trace(self, trace) -> None:
+        """Fold a finished trace into the registry's span metrics (the
+        scheduler's own when the registry keeps none)."""
+        getattr(self.registry, "metrics", self.metrics).record_trace(trace)
+
     def retry_after_s(self) -> float:
         """Seconds until the queue has likely drained enough to retry:
         per-worker backlog times the execution-time EMA, clamped to
@@ -440,17 +488,10 @@ class Scheduler:
                         queue_wait_ms=qw, exec_ms=ex))
             if dead:
                 continue
-            flight.t_start = time.monotonic()
-            if flight.param is not None and flight.trace is None:
+            self._start(flight, time.monotonic())
+            if flight.param is not None:
                 self._run_batch(flight)
                 continue
-            if flight.trace is not None:
-                flight.trace.thread = threading.current_thread().name
-                # forced traces never batch; record the (empty) assembly
-                # phase so batched and solo timelines stay comparable
-                t_asm = time.perf_counter()
-                flight.trace.add("batch_assemble",
-                                 time.perf_counter() - t_asm, batch=1)
             err: Exception | None = None
             result = None
             try:
@@ -541,7 +582,7 @@ class Scheduler:
         now = time.monotonic()
         for f in batch:
             if f.t_start is None:
-                f.t_start = now
+                self._start(f, now)
         # one token for the whole dispatch: live until the *latest* member
         # deadline, and cancelled only when every member's token is — a
         # batch keeps running as long as anyone still wants its answer
@@ -550,6 +591,9 @@ class Scheduler:
             kwargs = {"cancel": group} if self._batch_accepts_cancel else {}
             if self._batch_accepts_qids:
                 kwargs["query_ids"] = [f.query_id for f in batch]
+            if self._batch_accepts_traces and any(f.trace is not None
+                                                  for f in batch):
+                kwargs["traces"] = [f.trace for f in batch]
             out = self.registry.execute_canonical_batch(
                 leader.dataset, [f.param for f in batch], leader.version,
                 **kwargs)

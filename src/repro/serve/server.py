@@ -33,6 +33,9 @@ carries the span tree under ``"trace"``.  A registry-level
 ``trace_sample`` rate traces that fraction of ordinary requests on the
 fast path (zero-duration step spans) to feed the slow-query log and the
 ``repro_span_seconds`` histograms without the profiled path's overhead.
+The handler makes the decision when the request arrives, so one span tree
+covers the request from parse to the last byte written (``decode``,
+``serialize``, ``write``); the handler records it after the write.
 
 Requests flow through the :class:`~repro.serve.scheduler.Scheduler`, so
 identical concurrent queries coalesce and overload returns 503 rather than
@@ -41,6 +44,7 @@ piling onto the engine.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import random
@@ -55,7 +59,7 @@ from repro.core.planner import PlanError
 from repro.core.query import QueryBuildError
 from repro.core.sparql_exec import QueryResult, SparqlEngine
 from repro.obs import (DecisionJournal, SlowQueryLog, Trace,
-                       WorkloadProfiler)
+                       WorkloadProfiler, maybe_span)
 from repro.rdf.sparql import SparqlError
 from repro.resilience import faults
 from repro.resilience.cancel import CancelToken, QueryCancelled
@@ -72,6 +76,32 @@ log = get_logger("serve.server")
 
 class UnknownDataset(KeyError):
     pass
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _batch_span(traces: list | None, name: str, **meta):
+    """A span of work one batch shares.  It is recorded live on the first
+    traced member, which the context yields (``None`` when no member is
+    traced), and copied with its duration onto every other traced member,
+    marked ``shared``, so the work counts once.  An untraced batch
+    (``traces is None``) gets a shared no-op context."""
+    return _NO_SPAN if traces is None else _shared_span(traces, name, **meta)
+
+
+@contextlib.contextmanager
+def _shared_span(traces: list, name: str, **meta):
+    live = [t for t in traces if t is not None]
+    if not live:
+        yield None
+        return
+    t0 = time.perf_counter()
+    with live[0].span(name, **meta):
+        yield live[0]
+    dur = time.perf_counter() - t0
+    for t in live[1:]:
+        t.add(name, dur, shared=True, **meta)
 
 
 def _shape_key(shape: str) -> str:
@@ -186,6 +216,15 @@ class DatasetRegistry:
     def version(self, name: str) -> int:
         return self.get(name).version
 
+    def sample_trace(self) -> Trace | None:
+        """A sampled :class:`repro.obs.Trace` for ``trace_sample`` of the
+        calls, else ``None``; at rate 0 no trace is ever built.  Called once
+        per request, where it enters: the HTTP handler, the scheduler or
+        :meth:`execute`."""
+        if self.trace_sample > 0.0 and random.random() < self.trace_sample:
+            return Trace(sampled=True)
+        return None
+
     def invalidate(self, name: str) -> int:
         """Bump a dataset's graph version; retire its cached results.
         Call after mutating/reloading the graph in place.  The bump and
@@ -249,19 +288,16 @@ class DatasetRegistry:
                           query_id: str | None = None) -> QueryResult:
         """Execute over canonical variable names (scheduler entry point).
 
-        ``trace`` is a live :class:`repro.obs.Trace` (forced request);
-        when absent, ``trace_sample`` of executions get a sampled trace on
-        the fast path.  Traced executions bypass the result cache (there is
-        nothing to observe about returning a stored object) and feed the
-        slow-query log + span histograms.  ``cancel`` is the flight's
+        ``trace`` is the request's live :class:`repro.obs.Trace`, sampled
+        or forced where the request entered (:meth:`sample_trace`); its
+        creator finishes and records it.  Traced executions bypass the
+        result cache (there is nothing to observe about returning a stored
+        object) and feed the slow-query log.  ``cancel`` is the flight's
         cooperative-cancellation token: the executor polls it at chunk
         boundaries, so expired/abandoned requests stop occupying the
         device."""
         ds = self.get(name)
         key = (canon.fingerprint, version)
-        if trace is None and self.trace_sample > 0.0 \
-                and random.random() < self.trace_sample:
-            trace = Trace(sampled=True)
         if trace is not None:
             # correlation labels for the span tree / Chrome export
             if trace.query_id is None:
@@ -277,15 +313,9 @@ class DatasetRegistry:
                               query_id=query_id,
                               fingerprint=canon.fingerprint)
                 return hit
-        if trace is not None and trace.root.children:
-            # scheduler-submitted trace: account the time between the
-            # submitting thread's last span and this worker picking it up
-            last = trace.root.children[-1]
-            gap = trace._now() - (last.t0 + last.dur)
-            if gap > 0:
-                trace.add("queue_wait", gap)
-        compiled, fresh = ds.engine.compile_canonical(canon, with_fresh=True,
-                                                      trace=trace)
+        with maybe_span(trace, "plan"):
+            compiled, fresh = ds.engine.compile_canonical(
+                canon, with_fresh=True, trace=trace)
         if fresh:
             self.metrics.record_plan_search(compiled.plan_ms)
         self._journal("plan_cache", dataset=name, hit=not fresh,
@@ -366,23 +396,22 @@ class DatasetRegistry:
                           q_error=round(hint["q_error_median"], 2),
                           version=fb_version)
         if trace is not None:
-            trace.finish()
-            self.metrics.record_trace(trace)
-            explain = ds.engine.describe_compiled(compiled,
-                                                  run_stats=res.stats,
-                                                  inverse=canon.inverse)
-            if ds.slow_log.record(canon.fingerprint, trace.dur_ms, trace,
-                                  dataset=name, count=res.count,
-                                  explain=explain):
+            # the request's time so far; its creator may add more spans
+            if ds.slow_log.record(
+                    canon.fingerprint, trace.elapsed_ms(), trace,
+                    dataset=name, count=res.count,
+                    explain=lambda: ds.engine.describe_compiled(
+                        compiled, run_stats=res.stats,
+                        inverse=canon.inverse)):
                 self.metrics.slow_queries.inc(dataset=name)
-            res.stats["trace"] = trace.to_dict()
         elif ds.result_cache.enabled and version == ds.version:
             ds.result_cache.put(key, res)
         return res
 
     def execute_canonical_batch(self, name: str, pqs, version: int,
                                 cancel: CancelToken | None = None,
-                                query_ids: list[str] | None = None) -> list:
+                                query_ids: list[str] | None = None,
+                                traces: list | None = None) -> list:
         """Answer a same-shape batch in one parameterized dispatch
         (scheduler batch-leader entry point).
 
@@ -397,20 +426,28 @@ class DatasetRegistry:
         shape *and* constants, so this is the per-(shape, constants,
         graph_version) keying the batch path needs.  Shapes that cannot
         be parameterized fall back to per-member
-        :meth:`execute_canonical`."""
+        :meth:`execute_canonical`.
+
+        ``traces`` holds each member's live trace (or ``None``): traced
+        members get ``plan`` and ``execute`` spans naming the batch's size
+        and leader (``query_ids[0]``), recorded on the first traced member
+        and marked ``shared`` on the rest."""
         ds = self.get(name)
         self.metrics.batch_size.observe(len(pqs))
         if len(pqs) >= 2:
             self.metrics.coalesced_queries.inc(len(pqs))
         qids = query_ids or [None] * len(pqs)
+        batch = {"batch": len(pqs), "leader": qids[0]}
         out: list = [None] * len(pqs)
-        family = ds.engine.compile_param(pqs[0])
+        with _batch_span(traces, "plan", **batch) as lead:
+            family = ds.engine.compile_param(pqs[0], trace=lead)
         if family is None:
             self._journal("batch", dataset=name, size=len(pqs),
                           query_id=qids[0], parameterized=False)
             for i, pq in enumerate(pqs):
                 try:
                     out[i] = self.execute_canonical(name, pq.canon, version,
+                                                    trace=traces and traces[i],
                                                     cancel=cancel,
                                                     query_id=qids[i])
                 except Exception as e:  # noqa: BLE001 — per-member fan-out
@@ -430,8 +467,11 @@ class DatasetRegistry:
         if not todo:
             return out
         try:
-            results = ds.engine.execute_param_batch(
-                family, [pqs[i].consts for i in todo], cancel=cancel)
+            with _batch_span(traces and [traces[i] for i in todo],
+                             "execute", **batch) as lead:
+                results = ds.engine.execute_param_batch(
+                    family, [pqs[i].consts for i in todo], cancel=cancel,
+                    trace=lead)
         except Exception as e:  # noqa: BLE001 — fail the executed members
             for i in todo:
                 out[i] = e
@@ -466,12 +506,18 @@ class DatasetRegistry:
         return out
 
     def execute(self, name: str, sparql: str) -> QueryResult:
-        """Scheduler-less convenience path (tests, CLIs)."""
+        """Scheduler-less convenience path (tests, CLIs); samples its own
+        trace at ``trace_sample``, from the execution on."""
         from repro.serve.fingerprint import canonicalize_query
         from repro.rdf.sparql import parse_sparql
 
         canon = canonicalize_query(parse_sparql(sparql))
-        res = self.execute_canonical(name, canon, self.version(name))
+        trace = self.sample_trace()
+        res = self.execute_canonical(name, canon, self.version(name),
+                                     trace=trace)
+        if trace is not None:
+            trace.finish()
+            self.metrics.record_trace(trace)
         return QueryResult(canon.restore(res.variables), res.rows,
                            list(res.kinds), count=res.count)
 
@@ -538,16 +584,24 @@ class DatasetRegistry:
 # ------------------------------------------------------------------- HTTP
 def _bindings_json(registry: DatasetRegistry, dataset: str, res: QueryResult,
                    limit: int | None) -> dict:
-    rows = registry.decode(dataset, res, limit=limit)
-    bindings = []
-    for rec in rows:
-        b = {}
-        for var, term in rec.items():
-            if term is None:
-                continue
-            kind = "literal" if term.startswith('"') else "uri"
-            b[var] = {"type": kind, "value": term.strip('"')}
-        bindings.append(b)
+    """The SPARQL-JSON body of an answer: decoded terms (a ``decode``
+    span on the request's trace, ``res.stats["trace_obj"]``) built into
+    bindings (a ``serialize`` span)."""
+    trace = res.stats.get("trace_obj")
+    with maybe_span(trace, "decode") as sp:
+        rows = registry.decode(dataset, res, limit=limit)
+        if sp is not None:
+            sp.meta["rows"] = len(rows)
+    with maybe_span(trace, "serialize", rows=len(rows)):
+        bindings = []
+        for rec in rows:
+            b = {}
+            for var, term in rec.items():
+                if term is None:
+                    continue
+                kind = "literal" if term.startswith('"') else "uri"
+                b[var] = {"type": kind, "value": term.strip('"')}
+            bindings.append(b)
     return {"head": {"vars": list(res.variables)},
             "results": {"bindings": bindings},
             "stats": {"count": res.count, "returned": len(bindings)}}
@@ -716,8 +770,8 @@ class _Handler(BaseHTTPRequestHandler):
             explain_param = str(params.get("explain", "")).lower()
             explain = explain_param in ("1", "true", "yes", "analyze")
             analyze = explain_param == "analyze"
-            trace = (str(params.get("trace", "")).lower()
-                     in ("1", "true", "yes"))
+            forced = (str(params.get("trace", "")).lower()
+                      in ("1", "true", "yes"))
         except (ValueError, UnknownDataset) as e:
             self._error(400, str(e))
             return
@@ -747,10 +801,14 @@ class _Handler(BaseHTTPRequestHandler):
                     gate.release()
             return
         t0 = time.perf_counter()
+        # the one sampling decision for this request: its trace covers
+        # parse to the last byte written, and is recorded after the write
+        trace = (Trace(profile_steps=True) if forced
+                 else registry.sample_trace())
         try:
-            res = self.server.scheduler.submit(dataset, query,
-                                               timeout_s=timeout_s,
-                                               trace=trace)
+            res = self.server.scheduler.submit(
+                dataset, query, timeout_s=timeout_s,
+                trace=False if trace is None else trace)
         except UnknownDataset as e:
             self._error(404, f"unknown dataset: {e}")
         except (SparqlError, QueryBuildError, PlanError) as e:
@@ -810,11 +868,20 @@ class _Handler(BaseHTTPRequestHandler):
             out = _bindings_json(registry, dataset, res, limit)
             if qid:
                 out["query_id"] = qid
-            if trace and res.stats.get("trace") is not None:
-                out["trace"] = res.stats["trace"]
-            self._send_json(200, out,
-                            headers={"X-Repro-Query-Id": qid} if qid
-                            else None)
+            if forced:
+                # the span tree as of now: serialize and write come later
+                out["trace"] = trace.finish().to_dict()
+            with maybe_span(trace, "serialize") as sp:
+                body = json.dumps(out).encode()
+                if sp is not None:
+                    sp.meta["bytes"] = len(body)
+            with maybe_span(trace, "write", bytes=len(body)):
+                self._send(200, body, "application/json; charset=utf-8",
+                           headers={"X-Repro-Query-Id": qid} if qid
+                           else None)
+            if trace is not None:
+                trace.finish()
+                registry.metrics.record_trace(trace)
 
 
 class SparqlHTTPServer(ThreadingHTTPServer):
