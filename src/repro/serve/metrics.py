@@ -351,6 +351,10 @@ class ServeMetrics:
         self.compactions = r.counter(
             "repro_store_compactions_total",
             "live-store delta compactions (base graph rebuilds)")
+        self.answer_table_extended = r.counter(
+            "repro_answer_table_extended_total",
+            "fragments appended to a dataset's answer-encoding table for "
+            "ids it gained after it was built (updatable datasets)")
         self.span_seconds = r.labeled_histogram(
             "repro_span_seconds",
             "top-level trace span duration in seconds, by span name",

@@ -54,6 +54,8 @@ from dataclasses import dataclass, field
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qs, urlparse
 
+import numpy as np
+
 from repro.core.exec import ExecOpts
 from repro.core.planner import PlanError
 from repro.core.query import QueryBuildError
@@ -104,6 +106,78 @@ def _shared_span(traces: list, name: str, **meta):
         t.add(name, dur, shared=True, **meta)
 
 
+_ESCAPE = json.encoder.encode_basestring_ascii  # the escaper json.dumps uses
+
+
+def _fragments(terms) -> list[bytes]:
+    """What ``json.dumps`` writes for each term's SPARQL-JSON binding,
+    ``{"type": ..., "value": ...}``, by the rules of :func:`_bindings_json`:
+    a term that starts with ``"`` is a literal, and the value is the term
+    with its ``"`` stripped from both ends."""
+    return [(b'{"type": "literal", "value": ' if t.startswith('"')
+             else b'{"type": "uri", "value": ')
+            + _ESCAPE(t.strip('"')).encode() + b"}" for t in terms]
+
+
+class AnswerTable:
+    """A dataset's answer-encoding table: the JSON fragment of every
+    vertex's and every edge label's binding, as ``bytes`` in object arrays
+    indexed by id, so an answer column encodes as one take.  Each array
+    ends in one extra ``b""`` entry, which a null cell's id ``-1`` takes.
+
+    Ids are append-only, on an updatable dataset too (compaction keeps
+    them), so :meth:`extend` appends the fragments of ids the dataset's
+    maps gained since and rebuilds nothing.  It runs under the dataset
+    lock, under which updates grow the maps."""
+
+    def __init__(self, maps):
+        self.maps = maps
+        self.vertices = self.elabels = self._table([])
+        self.extend()
+
+    @staticmethod
+    def _table(frags: list[bytes]) -> np.ndarray:
+        out = np.empty(len(frags) + 1, dtype=object)
+        out[:-1] = frags
+        out[-1] = b""
+        return out
+
+    def extend(self) -> int:
+        """Append the fragments of the ids the maps gained; returns how
+        many (vertices and edge labels)."""
+        maps = self.maps
+        added = 0
+        for name, ids, strs in (
+                ("vertices", maps.vertex_to_term, maps.dict.terms.to_str),
+                ("elabels", maps.elabel_to_pred,
+                 maps.dict.predicates.to_str)):
+            table = getattr(self, name)
+            have = len(table) - 1
+            if len(ids) > have:
+                new = self._table(
+                    _fragments([strs[i] for i in ids[have:].tolist()]))
+                setattr(self, name, np.concatenate([table[:-1], new]))
+                added += len(new) - 1
+        return added
+
+    def take(self, ids: np.ndarray, kinds: list[str],
+             lock: threading.Lock) -> tuple[list[np.ndarray], int]:
+        """The fragments of each column of ``ids`` (``-1`` for a null),
+        whose kind is ``"vertex"`` or ``"predicate"``, and how many
+        fragments were appended first, under ``lock``, for ids the table
+        did not hold yet."""
+        def table(kind):
+            return self.vertices if kind == "vertex" else self.elabels
+
+        extended = 0
+        if len(ids) and any(ids[:, c].max() >= len(table(kind)) - 1
+                            for c, kind in enumerate(kinds)):
+            with lock:
+                extended = self.extend()
+        cells = [table(kind)[ids[:, c]] for c, kind in enumerate(kinds)]
+        return cells, extended
+
+
 def _shape_key(shape: str) -> str:
     """Short stable digest of a parameterized shape (the serialized shape
     AST is too long for journal entries / workload profile keys)."""
@@ -121,6 +195,7 @@ class HostedDataset:
     maps: object
     engine: SparqlEngine
     result_cache: ResultCache
+    answers: AnswerTable
     store: object = None  # VersionedStore when updatable
     version: int = 0
     lock: threading.Lock = field(default_factory=threading.Lock)
@@ -186,7 +261,8 @@ class DatasetRegistry:
         engine_graph = store.snapshot() if store is not None else graph
         engine = SparqlEngine(engine_graph, maps, opts, plan_cache=plan_cache)
         ds = HostedDataset(name=name, graph=graph, maps=maps, engine=engine,
-                           result_cache=result_cache, store=store,
+                           result_cache=result_cache,
+                           answers=AnswerTable(maps), store=store,
                            version=store.version if store is not None else 0,
                            slow_log=SlowQueryLog(self._slow_log_size))
         with self._lock:
@@ -584,9 +660,13 @@ class DatasetRegistry:
 # ------------------------------------------------------------------- HTTP
 def _bindings_json(registry: DatasetRegistry, dataset: str, res: QueryResult,
                    limit: int | None) -> dict:
-    """The SPARQL-JSON body of an answer: decoded terms (a ``decode``
-    span on the request's trace, ``res.stats["trace_obj"]``) built into
-    bindings (a ``serialize`` span)."""
+    """The plain dict encoder of an answer's SPARQL-JSON body: decoded
+    terms (a ``decode`` span on the request's trace,
+    ``res.stats["trace_obj"]``) built into bindings (a ``serialize``
+    span).  The served path encodes with :func:`_answer_body`; this is the
+    oracle it is tested against, byte for byte, and the benchmark's
+    ``benchmarks/chip/tests/test_reference.py`` and ``probes.py`` name
+    it."""
     trace = res.stats.get("trace_obj")
     with maybe_span(trace, "decode") as sp:
         rows = registry.decode(dataset, res, limit=limit)
@@ -605,6 +685,70 @@ def _bindings_json(registry: DatasetRegistry, dataset: str, res: QueryResult,
     return {"head": {"vars": list(res.variables)},
             "results": {"bindings": bindings},
             "stats": {"count": res.count, "returned": len(bindings)}}
+
+
+def _answer_body(registry: DatasetRegistry, dataset: str, res: QueryResult,
+                 limit: int | None, query_id: str | None = None,
+                 inline: Trace | None = None) -> bytes:
+    """The response body of an answer: ``json.dumps(out).encode()`` of
+    :func:`_bindings_json`'s ``out``, with ``query_id`` and the span tree
+    of ``inline`` (a forced trace, finished as of the assembly) added,
+    byte for byte.  Each column's cells are one take from the dataset's
+    :class:`AnswerTable` (a ``decode`` span: ``rows``, and ``extended``,
+    the fragments appended for ids the table did not hold yet); the rows
+    are one join of those fragments and the keys between them (a
+    ``serialize`` span: ``bytes``)."""
+    trace = res.stats.get("trace_obj")
+    ds = registry.get(dataset)
+    # a variable named twice keeps its first place and its last column,
+    # as the dict that _bindings_json builds a row in does
+    last = {var: c for c, var in enumerate(res.variables)}
+    total = res.rows.shape[0]
+    n = max(0, total if limit is None else min(limit, total))
+    cols = list(last.values())
+    with maybe_span(trace, "decode", rows=n) as sp:
+        # an answer with no branch to run holds rows of shape (0, 0)
+        ids = (res.rows[:n, cols] if n
+               else np.zeros((0, len(cols)), dtype=np.int32))
+        cells, extended = ds.answers.take(
+            ids, [res.kinds[c] for c in cols], ds.lock)
+        if sp is not None:
+            sp.meta["extended"] = extended
+    if extended:
+        registry.metrics.answer_table_extended.inc(extended, dataset=dataset)
+    tail = {"stats": {"count": res.count, "returned": n}}
+    if query_id:
+        tail["query_id"] = query_id
+    if inline is not None:
+        tail["trace"] = inline.finish().to_dict()
+    with maybe_span(trace, "serialize") as sp:
+        # row by row: each column's key (with ", " when a cell left of it
+        # is present; none for a null) and fragment; a row's first piece
+        # also closes the row before it
+        k = len(last)
+        present = ids >= 0
+        after = np.zeros((n, k), dtype=np.int8)
+        after[:, 1:] = np.logical_or.accumulate(present, axis=1)[:, :-1]
+        pieces = np.empty((n, max(1, 2 * k)), dtype=object)
+        pieces[:, 0] = b"}, {"
+        for c, var in enumerate(last):
+            key = _ESCAPE(var).encode() + b": "
+            lead = b"" if c else b"}, {"
+            choice = np.array([lead, lead, lead + key, lead + b", " + key],
+                              dtype=object)
+            pieces[:, 2 * c] = choice[2 * present[:, c] + after[:, c]]
+            pieces[:, 2 * c + 1] = cells[c]
+        if n:
+            pieces[0, 0] = pieces[0, 0][len(b"}, "):]
+        parts = [b'{"head": ', json.dumps({"vars": list(res.variables)})
+                 .encode(), b', "results": {"bindings": [']
+        parts += pieces.ravel().tolist()
+        parts += [b"}]}, " if n else b"]}, ", json.dumps(tail)[1:].encode()]
+        body = b"".join(parts)
+        del parts, pieces, cells  # releasing them is part of the work
+        if sp is not None:
+            sp.meta["bytes"] = len(body)
+    return body
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -865,16 +1009,8 @@ class _Handler(BaseHTTPRequestHandler):
             log_event(log, "sparql", query_id=qid, dataset=dataset,
                       status="ok", count=res.count,
                       ms=round((time.perf_counter() - t0) * 1e3, 3))
-            out = _bindings_json(registry, dataset, res, limit)
-            if qid:
-                out["query_id"] = qid
-            if forced:
-                # the span tree as of now: serialize and write come later
-                out["trace"] = trace.finish().to_dict()
-            with maybe_span(trace, "serialize") as sp:
-                body = json.dumps(out).encode()
-                if sp is not None:
-                    sp.meta["bytes"] = len(body)
+            body = _answer_body(registry, dataset, res, limit, qid,
+                                inline=trace if forced else None)
             with maybe_span(trace, "write", bytes=len(body)):
                 self._send(200, body, "application/json; charset=utf-8",
                            headers={"X-Repro-Query-Id": qid} if qid

@@ -101,7 +101,12 @@ def test_sampled_http_request_one_tree_recorded_after_write(
     assert "trace" not in out  # only a forced trace goes in the response
     # the request's spans share its query id, which the response carries
     assert trace.query_id == out["query_id"]
-    assert trace.find("decode")[0].meta["rows"] == out["stats"]["returned"]
+    # the answer is encoded by one table take, then one assembly
+    top = [s.name for s in trace.root.children]
+    assert top[top.index("decode"):] == ["decode", "serialize", "write"]
+    (decode,), (serialize,) = trace.find("decode"), trace.find("serialize")
+    assert decode.meta == {"rows": out["stats"]["returned"], "extended": 0}
+    assert serialize.meta["bytes"] == trace.find("write")[0].meta["bytes"]
     assert trace.find("write")[0].meta["bytes"] > 0
     # queue_wait comes from the flight's own times, before planning
     qw, plan = trace.find("queue_wait")[0], trace.find("plan")[0]
