@@ -32,8 +32,8 @@ def reading(workload: str, seed: int, seconds: float,
     """The control's compared numbers for one seed."""
     _, _, cfg, mix = R.load_cell(workload, root)
     overrides = overrides or {}
-    cfg = {**cfg, **overrides.get("config", {})}
-    mix = {**mix, **overrides.get("traffic", {})}
+    cfg = R.merge(cfg, overrides.get("config", {}))
+    mix = R.merge(mix, overrides.get("traffic", {}))
     t = time.monotonic()
     ds = R.generate(cfg, seed)
     plan = traffic.plan(mix, ds, seconds)

@@ -9,6 +9,13 @@ in the configured ratios, each with BSBM's properties.  Predicate names
 are the repository's B1-B12 (``b:propertyNumeric1``,
 ``b:reviewerHomepage``); departures from BSBM are the configuration's
 ``assumed`` list.
+
+As in ``datagen/lubm.py``, the counts come from a stream of their own,
+fixed by the configuration's ``count_seed``: how many features each product
+has, which of the optional properties 4-6 it has, which ratings each review
+has and which reviews carry a reviewer homepage.  Every seed then serves the
+same number of triples of each predicate.  The seed draws the rest:
+identities, links and values.
 """
 
 from __future__ import annotations
@@ -23,6 +30,7 @@ def generate(cfg: dict, seed: int) -> Dataset:
     r = cfg["ranges"]
     n_prod = int(cfg["products"])
     rng = np.random.default_rng([seed, 0])
+    sizes = np.random.default_rng([int(cfg["count_seed"]), 0])
     b = Builder()
     typ = b.pred(RDF_TYPE)
     add, entity, shared = b.add, b.entity, b.shared
@@ -94,6 +102,12 @@ def generate(cfg: dict, seed: int) -> Dataset:
     opt_num = r["optional_property_share"]
     rating_share = r["rating_share"]
     home_share = r["reviewer_homepage_share"]
+    n_off = int(r["offers_per_product"])
+    n_rev = int(r["reviews_per_product"])
+    n_feats = sizes.integers(lo_f, hi_f + 1, size=n_prod).tolist()
+    present = (sizes.random((n_prod, 6)) < opt_num).tolist()
+    rated = (sizes.random((n_prod, n_rev, 4)) < rating_share).tolist()
+    homes = (sizes.random((n_prod, n_rev)) < home_share).tolist()
     for p in range(n_prod):
         prod = entity(f"b:Product{p}")
         b.population("product", prod)
@@ -104,17 +118,15 @@ def generate(cfg: dict, seed: int) -> Dataset:
         add(prod, P["producer"], producers[int(rng.integers(n_producers))])
         add(prod, P["publisher"], producers[int(rng.integers(n_producers))])
         add(prod, P["publishDate"], dates[int(rng.integers(len(dates)))])
-        k = int(rng.integers(lo_f, hi_f + 1))
-        for f in rng.choice(n_feat, size=min(k, n_feat), replace=False).tolist():
+        for f in rng.choice(n_feat, size=min(n_feats[p], n_feat),
+                            replace=False).tolist():
             add(prod, P["productFeature"], features[f])
         nums = rng.integers(lo_num, hi_num + 1, size=6).tolist()
-        present = rng.random(6) < opt_num
         for i in range(6):
-            if i < 3 or present[i]:
+            if i < 3 or present[p][i]:
                 add(prod, P[f"propertyNumeric{i + 1}"], shared(f'"{nums[i]}"'))
                 add(prod, P[f"propertyTextual{i + 1}"],
                     entity(f'"product {p} text {i + 1}"'))
-        n_off = int(r["offers_per_product"])
         prices = rng.uniform(lo_p, hi_p, size=n_off).tolist()
         vend = rng.integers(n_vendors, size=n_off).tolist()
         days = rng.integers(lo_dd, hi_dd + 1, size=n_off).tolist()
@@ -132,11 +144,8 @@ def generate(cfg: dict, seed: int) -> Dataset:
                 entity(f'"http://vendor.example/offer{p}.{j}"'))
             add(off, P["publisher"], vendors[vend[j]])
             add(off, P["publishDate"], dates[d_from[j]])
-        n_rev = int(r["reviews_per_product"])
         who = rng.integers(n_reviewers, size=n_rev).tolist()
         ratings = rng.integers(1, 11, size=(n_rev, 4)).tolist()
-        rated = (rng.random((n_rev, 4)) < rating_share).tolist()
-        homes = (rng.random(n_rev) < home_share).tolist()
         when = rng.integers(len(dates), size=n_rev).tolist()
         for j in range(n_rev):
             rev = entity(f"b:Review{p}.{j}")
@@ -147,10 +156,10 @@ def generate(cfg: dict, seed: int) -> Dataset:
             add(rev, P["title"], entity(f'"review {p}.{j}"'))
             add(rev, P["text"], entity(f'"review {p}.{j} text"'))
             for i in range(4):
-                if rated[j][i]:
+                if rated[p][j][i]:
                     add(rev, P[f"rating{i + 1}"],
                         shared(f'"{ratings[j][i]}"'))
-            if homes[j]:
+            if homes[p][j]:
                 add(rev, P["reviewerHomepage"],
                     entity(f'"http://reviewer.example/{p}/{j}"'))
             add(rev, P["publisher"], reviewers[who[j]])

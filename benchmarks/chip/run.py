@@ -125,6 +125,19 @@ def load_cell(name: str, root: Path = ROOT) -> tuple[dict, dict, dict, dict]:
     return bench, cell, cfg, traffic.load(cell["traffic"])
 
 
+def merge(base: dict, over: dict) -> dict:
+    """``base`` with ``over`` laid on it, one level deep: where both values
+    are objects (``ranges``, ``server``) their keys merge, otherwise the
+    override wins.  With no overrides, as on the chip, ``base`` comes back
+    unchanged."""
+    out = dict(base)
+    for key, value in over.items():
+        if isinstance(value, dict) and isinstance(base.get(key), dict):
+            value = {**base[key], **value}
+        out[key] = value
+    return out
+
+
 def generate(cfg: dict, seed: int) -> Dataset:
     gen = importlib.import_module(f"benchmarks.chip.datagen.{cfg['generator']}")
     return gen.generate(cfg, seed)
@@ -287,8 +300,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         overrides: dict | None = None, log=print) -> dict:
     bench, cell, cfg, mix = load_cell(workload, root)
     overrides = overrides or {}
-    cfg = {**cfg, **overrides.get("config", {})}
-    mix = {**mix, **overrides.get("traffic", {})}
+    cfg = merge(cfg, overrides.get("config", {}))
+    mix = merge(mix, overrides.get("traffic", {}))
     devs = check_chips(int(cell["chips"]), require_tpu)
     import jax
 

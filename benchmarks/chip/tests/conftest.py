@@ -4,8 +4,9 @@
 
 Runs drive the real served path (data generator, the program's load path,
 HTTP server, scheduler, load generator child, reference check) with the
-chip check skipped, one university of three departments and a few
-requests a second.
+chip check skipped, at the size each configuration file's ``rehearsal``
+block gives (one university of three departments for ``lubm-50``) and a
+few requests a second.
 """
 
 from __future__ import annotations
@@ -27,18 +28,39 @@ import pytest  # noqa: E402
 SEED = 2**31 + 11
 
 
-def tiny(cfg_name: str = "lubm-50") -> dict:
-    cfg = json.loads((ROOT / "benchmarks/chip/configs" /
-                      f"{cfg_name}.json").read_text())
-    return {"universities": 1,
-            "ranges": {**cfg["ranges"], "departments": [3, 3]}}
+def rehearsal(cfg_file: Path) -> dict:
+    """The overrides that shrink a configuration to a size a CPU test run
+    holds: its file's ``rehearsal`` block, merged over the file one level
+    deep (``run.merge``).  The chip run never reads the block."""
+    cfg = json.loads(Path(cfg_file).read_text())
+    if "rehearsal" not in cfg:
+        raise ValueError(f"{cfg_file}: no 'rehearsal' key; every "
+                         "configuration file gives the size a CPU test run "
+                         "holds")
+    return cfg["rehearsal"]
+
+
+def rehearsed(name: str) -> dict:
+    """Configuration ``configs/<name>.json`` at its rehearsal size."""
+    from benchmarks.chip.run import merge
+
+    path = ROOT / "benchmarks/chip/configs" / f"{name}.json"
+    return merge(json.loads(path.read_text()), rehearsal(path))
+
+
+def config_file(cell: str, root: Path = ROOT) -> Path:
+    """The configuration file of a cell of ``root``'s BENCHMARK.json."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    name = {w["name"]: w for w in bench["workloads"]}[cell]["config"]
+    return root / {c["name"]: c for c in bench["configs"]}[name]["file"]
 
 
 @pytest.fixture
 def tiny_run(tmp_path, monkeypatch):
     """``tiny_run(cell, trace=False, rate=3.0, seconds=6.0, server={})``
-    runs a cell on the CPU at a tiny scale, with ``server`` settings over
-    the configuration's, and returns its result dict."""
+    runs a cell on the CPU at its configuration's rehearsal size, with
+    ``server`` settings over the configuration's, and returns its result
+    dict."""
     from benchmarks.chip import run as R
 
     monkeypatch.setattr(R, "CACHE_DIR", tmp_path / "jax_cache")
@@ -47,9 +69,8 @@ def tiny_run(tmp_path, monkeypatch):
     def go(cell: str, trace: bool = False, rate: float = 3.0,
            seconds: float = 6.0, root: Path = ROOT, log=None,
            server: dict | None = None):
-        cfg = R.load_cell(cell, root)[2]
-        over = {"config": {**tiny(),
-                           "server": {**cfg["server"], **(server or {})}},
+        size = rehearsal(config_file(cell, root))
+        over = {"config": R.merge(size, {"server": server or {}}),
                 "traffic": {"rate_qps": rate, "warmup_per_template": 1}}
         return R.run(cell, SEED, seconds, trace, require_tpu=False,
                      root=root, overrides=over,

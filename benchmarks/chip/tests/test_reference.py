@@ -12,7 +12,7 @@ from benchmarks.chip import traffic
 from benchmarks.chip.answers import digest_response
 from benchmarks.chip.reference import Reference, parse
 from benchmarks.chip.run import generate
-from benchmarks.chip.tests.conftest import ROOT, SEED, tiny
+from benchmarks.chip.tests.conftest import SEED, rehearsed
 
 
 def _engine(ds):
@@ -36,20 +36,19 @@ def _served(reg, query: str) -> bytes:
                                      None)).encode()
 
 
+def _rehearsed(name: str):
+    ds = generate(rehearsed(name), SEED)
+    return ds, Reference(ds), _engine(ds)
+
+
 @pytest.fixture(scope="module")
 def lubm():
-    cfg = json.loads((ROOT / "benchmarks/chip/configs/lubm-50.json")
-                     .read_text())
-    ds = generate({**cfg, **tiny()}, SEED)
-    return ds, Reference(ds), _engine(ds)
+    return _rehearsed("lubm-50")
 
 
 @pytest.fixture(scope="module")
 def bsbm():
-    cfg = json.loads((ROOT / "benchmarks/chip/configs/bsbm-20k.json")
-                     .read_text())
-    ds = generate({**cfg, "products": 300}, SEED)
-    return ds, Reference(ds), _engine(ds)
+    return _rehearsed("bsbm-20k")
 
 
 def _cases(mix_name: str, skip=()):

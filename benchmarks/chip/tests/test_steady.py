@@ -1,25 +1,22 @@
 """Every seed serves data of the same size and sends the same schedule;
-the seed changes only which links the data holds.  (The number of terms
-is the same too once every degree university is drawn, as at the
-configuration's 50 universities; at this test's size some are not.)"""
+the seed changes only which links, and in BSBM which values, the data
+holds.  (In LUBM the number of terms is the same too once every degree
+university is drawn, as at the configuration's 50 universities; at this
+test's size some are not.)"""
 
 from __future__ import annotations
-
-import json
 
 import numpy as np
 import pytest
 
 from benchmarks.chip import traffic
-from benchmarks.chip.datagen import lubm
-from benchmarks.chip.tests.conftest import ROOT, SEED, tiny
+from benchmarks.chip.datagen import bsbm, lubm
+from benchmarks.chip.tests.conftest import SEED, rehearsed
 
 
 @pytest.fixture(scope="module")
 def two_seeds():
-    cfg = json.loads((ROOT / "benchmarks/chip/configs/lubm-50.json")
-                     .read_text())
-    cfg = {**cfg, **tiny()}
+    cfg = rehearsed("lubm-50")
     return lubm.generate(cfg, 1), lubm.generate(cfg, SEED)
 
 
@@ -44,3 +41,13 @@ def test_replanned_templates_warm_up_past_their_replans(two_seeds):
     for t in m["templates"]:
         assert warm.count(t["name"]) == t.get("warmup",
                                               m["warmup_per_template"])
+
+
+def test_bsbm_seed_changes_values_not_counts():
+    cfg = rehearsed("bsbm-20k")
+    a, b = bsbm.generate(cfg, 1), bsbm.generate(cfg, SEED)
+    assert a.n_triples == b.n_triples
+    assert a.preds == b.preds
+    assert (np.bincount(a.p, minlength=len(a.preds)).tolist()
+            == np.bincount(b.p, minlength=len(b.preds)).tolist())
+    assert not (np.array_equal(a.s, b.s) and np.array_equal(a.o, b.o))
